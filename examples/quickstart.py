@@ -42,9 +42,10 @@ for r in ranked[:5]:
 print(f"worst: block={ranked[-1].config['block']}: {ranked[-1].prediction.glups:.1f} GLup/s\n")
 
 # -- 5: the TPU adaptation picks Pallas block shapes the same way -------------
+from repro.core.machine import TPU_V5E
 from repro.kernels.stencil25 import select_block
 
-blk, test = select_block((256, 256, 512), r=4)
+blk, test = select_block((256, 256, 512), r=4, machine=TPU_V5E)
 print(
     f"TPU Pallas stencil tile for a 256x256x512 grid: {blk} "
     f"(VMEM {test.vmem_bytes >> 20} MiB, limiter {test.limiter}, "

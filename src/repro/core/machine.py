@@ -133,7 +133,11 @@ class TPUMachine:
     bw_hbm: float = 819e9  # B/s per chip
     hbm_bytes: int = 16 * 2**30
     vmem_bytes: int = 128 * 2**20
-    vmem_usable: int = 100 * 2**20  # leave headroom for XLA-reserved scratch
+    # The estimator's VMEM gate and the scoped-VMEM limit every Pallas kernel
+    # compiles under (``vmem_limit_bytes``): one number, so a config the
+    # estimator calls feasible is one the compiler accepts.  Headroom below
+    # vmem_bytes is left for the compiler's internal scratch.
+    vmem_usable: int = 100 * 2**20
     bw_ici_link: float = 50e9  # B/s per link per direction
     ici_links: int = 4  # 2D torus: +-x, +-y
     bw_inter_pod: float = 25e9  # effective per-chip cross-pod (DCN-assisted) B/s
@@ -206,6 +210,42 @@ def get_machine(name: str) -> GPUMachine | TPUMachine:
     """Resolve a machine by registry key, full model name, or any
     case/punctuation variant thereof; unknown names get a did-you-mean."""
     return MACHINES[canonical_machine_name(name)]
+
+
+# ``device_kind`` as JAX reports it -> registry key.  A chip that is not
+# listed has no machine model: that is an error, never a fallback.
+TPU_DEVICE_KINDS: dict[str, str] = {
+    "TPU v5 lite": "TPUv5e",
+    "TPU v6 lite": "TPUv6e",
+}
+
+
+def tpu_machine(device_kind: str) -> TPUMachine:
+    """The :class:`TPUMachine` of a JAX ``device_kind``; unknown kinds raise."""
+    key = TPU_DEVICE_KINDS.get(device_kind)
+    if key is None:
+        raise KeyError(
+            f"no TPUMachine for device_kind {device_kind!r}; known kinds: "
+            f"{sorted(TPU_DEVICE_KINDS)}"
+        )
+    return MACHINES[key]
+
+
+def device_machine() -> TPUMachine:
+    """The :class:`TPUMachine` of the chip JAX runs on (``jax.devices()[0]``).
+
+    Raises when JAX's device is not a TPU: estimator-selected kernels compile
+    for a chip, and there is no CPU stand-in for one.
+    """
+    import jax
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"no TPU: JAX's first device is {dev.platform!r} ({dev.device_kind}); "
+            "estimator-selected Pallas kernels need a TPU chip"
+        )
+    return tpu_machine(dev.device_kind)
 
 
 def gpu_machines() -> dict[str, GPUMachine]:

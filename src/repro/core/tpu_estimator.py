@@ -22,7 +22,11 @@ Per candidate configuration we estimate:
     revisiting rule: an operand block is NOT refetched when its index_map output is
     unchanged between consecutive grid steps;
   * VMEM residency (double-buffered working set) -> hard feasibility gate (the
-    TPU analogue of the paper's capacity-miss model, but deterministic);
+    TPU analogue of the paper's capacity-miss model, but deterministic).  The
+    gate is ``machine.vmem_usable``, the same number every kernel passes to
+    the compiler as ``vmem_limit_bytes``;
+  * Mosaic's block-shape rule -> a second hard gate: the last two block dims
+    must be multiples of (sublanes, lanes) or span the whole array;
   * sublane/lane padding waste -> effective-bandwidth derating (the TPU analogue of
     the paper's L1 bank conflicts);
   * MXU/VPU compute time and the multi-limiter prediction max(T_compute, T_HBM).
@@ -114,6 +118,7 @@ class TPUEstimate:
     t_compute: float = 0.0
     t_grid: float = 0.0
     detail: dict = field(default_factory=dict)
+    misaligned: tuple[str, ...] = ()  # operands whose blocks break Mosaic's rule
 
     @property
     def time(self) -> float:
@@ -124,12 +129,28 @@ class TPUEstimate:
     @property
     def limiter(self) -> str:
         if not self.feasible:
-            return "VMEM"
+            return "TILING" if self.misaligned else "VMEM"
         terms = {"HBM": self.t_hbm, "COMPUTE": self.t_compute, "GRID": self.t_grid}
         return max(terms, key=terms.get)
 
 
 GRID_STEP_OVERHEAD_S = 2e-7  # per-step sequencer floor (mostly hidden by pipelining)
+
+
+def _breaks_tiling(tile, coeffs, offset, grid, machine: TPUMachine) -> bool:
+    """Mosaic refuses a block whose last two dims are not multiples of
+    (sublanes, lanes) unless the block spans the whole array.  The IR holds no
+    array extents, so a dim whose block index is 0 at every grid step is taken
+    to span the array; a dim whose index moves must be aligned."""
+    for dim, multiple in ((-1, machine.lanes), (-2, machine.sublanes)):
+        if len(tile) < -dim:
+            continue
+        moves = offset[dim] != 0 or any(
+            c and g > 1 for c, g in zip(coeffs[dim], grid)
+        )
+        if moves and tile[dim] % multiple:
+            return True
+    return False
 
 
 def estimate_ir(ir: AccessIR, machine: TPUMachine = TPU_V5E) -> TPUEstimate:
@@ -148,7 +169,10 @@ def estimate_ir(ir: AccessIR, machine: TPUMachine = TPU_V5E) -> TPUEstimate:
     hbm_comp = 0.0
     useful = 0.0
     padded_total = 0.0
+    misaligned = []
     for acc in ir.accesses:
+        if _breaks_tiling(acc.tile, acc.coeffs, acc.offset, ir.iter_shape, machine):
+            misaligned.append(acc.field)
         dtype_bits = fields[acc.field].dtype_bits
         esize = dtype_bits / 8
         block_elems = int(np.prod(acc.tile)) if acc.tile else 1
@@ -181,7 +205,7 @@ def estimate_ir(ir: AccessIR, machine: TPUMachine = TPU_V5E) -> TPUEstimate:
             "padded_bytes": padded_bytes,
         }
     layout_eff = (useful / padded_total) if padded_total else 1.0
-    feasible = vmem <= machine.vmem_usable
+    feasible = vmem <= machine.vmem_usable and not misaligned
     est = TPUEstimate(
         config=ir.name,
         feasible=feasible,
@@ -191,6 +215,7 @@ def estimate_ir(ir: AccessIR, machine: TPUMachine = TPU_V5E) -> TPUEstimate:
         hbm_redundant=hbm_total - hbm_comp,
         layout_efficiency=layout_eff,
         detail=detail,
+        misaligned=tuple(misaligned),
     )
     est.t_hbm = hbm_total / machine.bw_hbm
     peak = machine.peak_flops(
@@ -277,14 +302,23 @@ def rank_configs(
 
 
 def select_config(
-    candidates: Sequence[PallasConfig], machine: TPUMachine = TPU_V5E
+    candidates: Sequence[PallasConfig], machine: TPUMachine
 ) -> tuple[PallasConfig, TPUEstimate]:
-    """Pick the best feasible candidate; raise if none fits VMEM."""
+    """Pick the best feasible candidate for ``machine``; raise if none is
+    feasible or if there is no machine (an interpreted kernel runs on no
+    chip, so its caller must name the configuration)."""
+    if not isinstance(machine, TPUMachine):
+        raise ValueError(
+            f"cannot select a Pallas config for machine {machine!r}: pass the "
+            "TPUMachine of the chip it will run on (core.machine.device_machine)"
+        )
     ranked = rank_configs(candidates, machine)
     best, est = ranked[0]
     if not est.feasible:
         raise ValueError(
             f"no feasible Pallas config: best candidate {best.name} needs "
-            f"{est.vmem_bytes/2**20:.1f} MiB VMEM > {machine.vmem_usable/2**20:.0f} MiB"
+            f"{est.vmem_bytes/2**20:.1f} MiB VMEM of "
+            f"{machine.vmem_usable/2**20:.0f} MiB, misaligned blocks: "
+            f"{list(est.misaligned)}"
         )
     return best, est

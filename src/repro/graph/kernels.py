@@ -43,6 +43,14 @@ def _divisor_leq(n: int, cap: int) -> int:
     return best
 
 
+def _tpu_tile(n: int, cap: int, multiple: int) -> int:
+    """Block extent for a dim of ``n``: the largest power-of-two divisor up to
+    ``cap`` when it is a multiple of ``multiple`` (Mosaic's (8, 128) block
+    rule), else all of ``n`` (a block spanning the dim is always legal)."""
+    best = _divisor_leq(n, cap)
+    return best if best % multiple == 0 else n
+
+
 def matmul_ir(m: int, n: int, k: int, *, backend: str, tag: str = "") -> tuple[AccessIR, int]:
     """(M, K) x (K, N) matmul node kernel -> (ir, repeat)."""
     if min(m, n, k) < 1:
@@ -69,9 +77,9 @@ def matmul_ir(m: int, n: int, k: int, *, backend: str, tag: str = "") -> tuple[A
         )
         return ir, k // kp
     # TPU: block-granular tiled matmul, k innermost grid dim (accumulate)
-    bm = _divisor_leq(m, 256)
-    bn = _divisor_leq(n, 256)
-    bk = _divisor_leq(k, 256)
+    bm = _tpu_tile(m, 256, 8)
+    bn = _tpu_tile(n, 256, 128)
+    bk = _tpu_tile(k, 256, 128)  # minor dim of a, second-minor of b
     a = IRField("a", (m, k), DTYPE_BITS)
     b = IRField("b", (k, n), DTYPE_BITS)
     c = IRField("c", (m, n), DTYPE_BITS)
@@ -131,7 +139,7 @@ def elementwise_ir(
     lanes = 128
     if nelem % lanes == 0:
         rows = nelem // lanes
-        rb = _divisor_leq(rows, 1024)
+        rb = _tpu_tile(rows, 1024, 8)
         grid = (rows // rb,)
         tile = (rb, lanes)
         coeffs = ((1,), (0,))

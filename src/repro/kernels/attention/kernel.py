@@ -81,6 +81,7 @@ def flash_attention_pallas(
     causal: bool = True,
     block_q: int = 128,
     block_kv: int = 128,
+    vmem_limit_bytes: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     b, hq, sq, d = q.shape
@@ -121,6 +122,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(b, hq, sq, d)

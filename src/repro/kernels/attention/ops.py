@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPU_V5E, TPUMachine
+from ...core.machine import TPUMachine, device_machine
 
 # GPU-space entry: the AccessIR builder that pushes this kernel through the
 # paper §III analytic pipeline (registry kernel "attention", backend "gpu").
@@ -102,13 +102,15 @@ def select_blocks(
     d: int,
     dtype=jnp.bfloat16,
     causal: bool = True,
-    machine: TPUMachine = TPU_V5E,
+    *,
+    machine: TPUMachine,
 ) -> tuple[tuple[int, int], te.TPUEstimate]:
     bits = jnp.dtype(dtype).itemsize * 8
     cands = config_space(b, hq, hkv, s, d, bits, causal)
     if not cands:
-        # sequences smaller than the smallest candidate: single block
-        return (s, s), None
+        raise ValueError(
+            f"no candidate blocks {CANDIDATE_BLOCKS} divide sequence length {s}"
+        )
     cfg, est = te.select_config(cands, machine)
     return (cfg.meta["block_q"], cfg.meta["block_kv"]), est
 
@@ -125,14 +127,21 @@ def flash_attention(
     block_kv: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
+    """GQA flash attention; blocks and VMEM limit as in
+    :func:`stencil25.ops.stencil25` (either block left ``None`` is picked)."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]
+    machine = None if interpret else device_machine()
     if block_q is None or block_kv is None:
-        (bq, bkv), _ = select_blocks(b, hq, hkv, s, d, q.dtype, causal)
-        block_q = block_q or min(bq, s)
-        block_kv = block_kv or min(bkv, s)
+        (bq, bkv), _ = select_blocks(
+            b, hq, hkv, s, d, q.dtype, causal, machine=machine
+        )
+        block_q = block_q or bq
+        block_kv = block_kv or bkv
     return flash_attention_pallas(
-        q, k, v, causal=causal, block_q=block_q, block_kv=block_kv, interpret=interpret
+        q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
+        interpret=interpret,
+        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
     )
 
 

@@ -14,6 +14,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import DIRS, WEIGHTS
 
@@ -91,6 +92,7 @@ def lbm_step_pallas(
     tau: float = 0.8,
     width: float = 4.0,
     block: tuple[int, int] = (8, 8),
+    vmem_limit_bytes: int | None = None,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One LB interface-tracking step; valid on the interior (1-cell shell excluded)."""
@@ -146,5 +148,6 @@ def lbm_step_pallas(
             jax.ShapeDtypeStruct(f.shape, f.dtype),
             jax.ShapeDtypeStruct(phase.shape, phase.dtype),
         ),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*([fp] * 9 + [pp] * 9 + [vp]))

@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPU_V5E, TPUMachine
+from ...core.machine import TPUMachine, device_machine
 from .kernel import lbm_step_pallas
 from .ref import init_fields, lbm_step_ref
 
@@ -69,7 +69,7 @@ def config_space(shape: tuple[int, int, int], dtype_bits: int):
 
 
 def select_block(
-    shape: tuple[int, int, int], dtype=jnp.float32, machine: TPUMachine = TPU_V5E
+    shape: tuple[int, int, int], dtype=jnp.float32, *, machine: TPUMachine
 ) -> tuple[tuple[int, int], te.TPUEstimate]:
     bits = jnp.dtype(dtype).itemsize * 8
     cands = config_space(shape, bits)
@@ -89,10 +89,13 @@ def lbm_step(
     block: tuple[int, int] | None = None,
     interpret: bool = False,
 ):
+    """One LB step; block and VMEM limit as in :func:`stencil25.ops.stencil25`."""
+    machine = None if interpret else device_machine()
     if block is None:
-        block, _ = select_block(f.shape[1:], f.dtype)
+        block, _ = select_block(f.shape[1:], f.dtype, machine=machine)
     return lbm_step_pallas(
-        f, phase, vel, tau=tau, width=width, block=block, interpret=interpret
+        f, phase, vel, tau=tau, width=width, block=block, interpret=interpret,
+        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
     )
 
 

@@ -15,6 +15,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .ref import star_offsets, star_weights_np
 
@@ -46,6 +47,7 @@ def stencil25_pallas(
     src: jnp.ndarray,
     r: int = 4,
     block: tuple[int, int] = (16, 16),
+    vmem_limit_bytes: int | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Apply the stencil to ``src`` (nz, ny, nx).
@@ -86,5 +88,6 @@ def stencil25_pallas(
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((nz, ny, nx), src.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*([padded] * 9))

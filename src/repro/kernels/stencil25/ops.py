@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPU_V5E, TPUMachine
+from ...core.machine import TPUMachine, device_machine
 from .kernel import stencil25_pallas
 from .ref import stencil25_ref
 
@@ -65,7 +65,8 @@ def select_block(
     shape: tuple[int, int, int],
     r: int = 4,
     dtype=jnp.float32,
-    machine: TPUMachine = TPU_V5E,
+    *,
+    machine: TPUMachine,
 ) -> tuple[tuple[int, int], te.TPUEstimate]:
     """Estimator-guided configuration selection (the paper's selection problem)."""
     bits = jnp.dtype(dtype).itemsize * 8
@@ -83,10 +84,19 @@ def stencil25(
     block: tuple[int, int] | None = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Range-r 3D star stencil; picks the block via the estimator when not given."""
+    """Range-r 3D star stencil; picks the block via the estimator when not given.
+
+    On the chip the block is selected for, and compiled under the VMEM limit
+    of, :func:`device_machine`; ``interpret=True`` runs on no chip and needs
+    an explicit ``block``.
+    """
+    machine = None if interpret else device_machine()
     if block is None:
-        block, _ = select_block(src.shape, r, src.dtype)
-    return stencil25_pallas(src, r=r, block=block, interpret=interpret)
+        block, _ = select_block(src.shape, r, src.dtype, machine=machine)
+    return stencil25_pallas(
+        src, r=r, block=block, interpret=interpret,
+        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
+    )
 
 
 __all__ = ["stencil25", "stencil25_ref", "select_block", "config_space"]

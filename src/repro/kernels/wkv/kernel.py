@@ -35,35 +35,47 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, state, *, L: int, K: i
     wlog = w_ref[0].astype(jnp.float32)
     u = u_ref[...].astype(jnp.float32)  # (1, K)
 
-    lam = jnp.cumsum(wlog, axis=0)  # (L, K)
-    lam_prev = jnp.concatenate([jnp.zeros((1, K), jnp.float32), lam[:-1]], axis=0)
+    # Every product is an MXU matmul at HIGHEST precision, so the f32
+    # log-decays are not rounded to bf16.  Prefix sums over the chunk are
+    # lower-triangular ones matmuls: Mosaic has no cumsum lowering.
+    def mm(a, b, contract=((1,), (0,))):
+        return jax.lax.dot_general(
+            a, b, (contract, ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    tri = row > col
+    lam = mm((row >= col).astype(jnp.float32), wlog)  # (L, K) inclusive
+    lam_prev = mm(tri.astype(jnp.float32), wlog)  # exclusive
     seg = lam_prev[:, None, :] - lam[None, :, :]  # (Lt, Ls, K)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0) > jax.lax.broadcasted_iota(
-        jnp.int32, (L, L), 1
+    tri3 = jax.lax.broadcasted_iota(jnp.int32, (L, L, K), 0) > (
+        jax.lax.broadcasted_iota(jnp.int32, (L, L, K), 1)
     )
-    seg = jnp.where(tri[:, :, None], seg, -60.0)
-    decay = jnp.exp(seg)
+    seg = jnp.where(tri3, seg, -60.0)
     # A[t,s] = sum_k r[t,k] decay[t,s,k] k[s,k]
-    a = jnp.einsum("tk,tsk,sk->ts", r, decay, k)
-    out = jax.lax.dot_general(
-        a, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    a = jnp.sum(r[:, None, :] * jnp.exp(seg) * k[None, :, :], axis=2)
+    out = mm(a, v)
     bonus = jnp.sum(r * (u * k), axis=1, keepdims=True)  # (L, 1)
     out = out + bonus * v
     s0 = state[...]
-    out = out + jax.lax.dot_general(
-        r * jnp.exp(lam_prev), s0, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
+    out = out + mm(r * jnp.exp(lam_prev), s0)
+    lam_end = lam[L - 1 :, :]  # (1, K)
+    inj = mm(k * jnp.exp(lam_end - lam), v, ((0,), (0,)))  # (K, V)
+    # diag(exp(lam_end)) @ S: scales row i of the state by its decay
+    eye = jax.lax.broadcasted_iota(jnp.int32, (K, K), 0) == (
+        jax.lax.broadcasted_iota(jnp.int32, (K, K), 1)
     )
-    tail = jnp.exp(lam[-1:, :] - lam)  # (L, K)
-    inj = jax.lax.dot_general(
-        (k * tail).T, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (K, V)
-    state[...] = jnp.exp(lam[-1])[:, None] * s0 + inj
+    state[...] = mm(jnp.where(eye, jnp.exp(lam_end), 0.0), s0) + inj
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-def wkv_pallas(r, k, v, wlog, u, chunk: int = 64, interpret: bool = False):
+def wkv_pallas(
+    r, k, v, wlog, u, chunk: int = 64, vmem_limit_bytes: int | None = None,
+    interpret: bool = False,
+):
     """r,k,v,wlog: (BH, S, K); u: (K,). Returns out (BH, S, K)."""
     BH, S, K = r.shape
     if S % chunk:
@@ -79,5 +91,6 @@ def wkv_pallas(r, k, v, wlog, u, chunk: int = 64, interpret: bool = False):
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((BH, S, K), r.dtype),
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(r, k, v, wlog, u2)

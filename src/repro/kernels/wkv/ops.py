@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPU_V5E, TPUMachine
+from ...core.machine import TPUMachine, device_machine
 
 # GPU-space entry: the AccessIR builder that pushes this kernel through the
 # paper §III analytic pipeline (registry kernel "wkv", backend "gpu").
@@ -48,21 +48,28 @@ def config_space(BH: int, S: int, K: int, dtype_bits: int = 32):
 
 
 def select_chunk(
-    BH: int, S: int, K: int, machine: TPUMachine = TPU_V5E
+    BH: int, S: int, K: int, *, machine: TPUMachine
 ) -> tuple[int, te.TPUEstimate]:
     cands = config_space(BH, S, K)
     if not cands:
-        return min(S, 16), None
+        raise ValueError(
+            f"no candidate chunk {CANDIDATE_CHUNKS} divides sequence length {S}"
+        )
     cfg, est = te.select_config(cands, machine)
     return cfg.meta["chunk"], est
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def wkv(r, k, v, wlog, u, chunk: int | None = None, interpret: bool = False):
+    """Chunked WKV; chunk and VMEM limit as in :func:`stencil25.ops.stencil25`."""
     BH, S, K = r.shape
+    machine = None if interpret else device_machine()
     if chunk is None:
-        chunk, _ = select_chunk(BH, S, K)
-    return wkv_pallas(r, k, v, wlog, u, chunk=chunk, interpret=interpret)
+        chunk, _ = select_chunk(BH, S, K, machine=machine)
+    return wkv_pallas(
+        r, k, v, wlog, u, chunk=chunk, interpret=interpret,
+        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
+    )
 
 
 __all__ = ["wkv", "wkv_ref", "select_chunk", "config_space", "wkv_gpu_ir"]

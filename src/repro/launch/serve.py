@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from ..configs import get_arch
+from .compile_cache import enable_compile_cache
 from ..models.params import init_params
 from ..models.registry import build_model
 from ..serve.engine import ServeEngine
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

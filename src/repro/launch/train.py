@@ -20,6 +20,7 @@ from ..data.pipeline import SyntheticTokenDataset
 from ..models.registry import build_model
 from ..optim.optimizers import make_optimizer
 from ..train.trainer import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 from .mesh import make_production_mesh, make_test_mesh
 
 
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     if args.smoke:
         cfg = cfg.smoke()

@@ -258,22 +258,17 @@ class LM:
         }
 
     def decode_step(self, params, cache, tokens):
-        """tokens (B, 1) -> (logits (B, 1, V), new_cache)."""
+        """tokens (B, S) -> (logits (B, S, V), new_cache): one decode step
+        (S = 1) or a prefill of S tokens appended to the cache."""
         cfg = self.cfg
-        B = tokens.shape[0]
+        B, S = tokens.shape
         h = self._embed(params, tokens)
-        if cfg.family == "ssm":
-            positions = None
-            caches = cache
-        elif cfg.family == "hybrid":
-            pos0 = cache[1]["len"][0]
-            positions = jnp.broadcast_to(pos0[None, None], (B, 1))
-            caches = cache
-        else:
-            pos0 = cache["len"][0]
-            positions = jnp.broadcast_to(pos0[None, None], (B, 1))
-            caches = cache
-        h, _, new_cache = self._run_blocks(params, h, positions, caches=caches)
+        positions = None
+        if cfg.family != "ssm":
+            # a prefill of S tokens occupies positions len .. len + S - 1
+            pos0 = (cache[1] if cfg.family == "hybrid" else cache)["len"][0]
+            positions = jnp.broadcast_to(pos0 + jnp.arange(S)[None, :], (B, S))
+        h, _, new_cache = self._run_blocks(params, h, positions, caches=cache)
         h = apply_norm(cfg, params.get("final_norm", {}), h)
         return self._head(params, h), new_cache
 

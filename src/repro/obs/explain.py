@@ -87,7 +87,7 @@ class PruneVerdict:
     """What the pruning layer would say about this config."""
 
     would_prune: bool
-    rule: str | None  # "sanity" | "roofline" | "vmem" | None (survives)
+    rule: str | None  # "sanity" | "roofline" | "vmem" | "tiling" | None (survives)
     detail: str
 
     def to_json(self) -> dict:
@@ -432,15 +432,25 @@ def explain_tpu_record(rec, ir, machine) -> ExplainReport:
         limiter = attribute_limiters(terms)
     else:
         limiter = LimiterAttribution(
-            limiter="VMEM",
+            limiter=est.limiter,
             time_s=float("inf"),
             runner_up=None,
             runner_up_time_s=None,
             margin=None,
             terms={"HBM": est.t_hbm, "COMPUTE": est.t_compute, "GRID": est.t_grid},
         )
+    misaligned = list(est.misaligned)
     prune = (
         PruneVerdict(
+            would_prune=True,
+            rule="tiling",
+            detail=(
+                f"blocks of {misaligned} break the ({machine.sublanes}, "
+                f"{machine.lanes}) block-shape rule (hard gate)"
+            ),
+        )
+        if misaligned
+        else PruneVerdict(
             would_prune=True,
             rule="vmem",
             detail=(

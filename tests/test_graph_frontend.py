@@ -44,7 +44,7 @@ def test_mesh_spec_roundtrips():
 
 def test_mesh_spec_reads_jax_mesh_axis_names():
     jax = pytest.importorskip("jax")
-    am = jax.sharding.AbstractMesh((("data", 4), ("model", 2)))
+    am = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
     spec = mesh_spec(am)
     assert spec.axes == (("data", 4), ("model", 2))
     # and the traced DAG carries those axis names on its collectives

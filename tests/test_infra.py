@@ -179,3 +179,26 @@ ENTRY %main.1 (x: f32[128,128]) -> f32[128,128] {
     assert len(ar) == 1
     expected = 2 * (128 * 128 * 4) * (3 / 4) * 10
     assert ar[0].wire_bytes == pytest.approx(expected)
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_dir_is_fixed(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set; without it the
+    cache goes to one fixed directory in the checkout."""
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, enable_compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env_dir))
+    try:
+        got = enable_compile_cache()
+        if env_dir is None:
+            assert got == str(CHECKOUT_CACHE_DIR) == jax.config.jax_compilation_cache_dir
+            assert (CHECKOUT_CACHE_DIR.parent / "chip_smoke.py").exists()
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == was
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

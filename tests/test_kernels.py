@@ -5,7 +5,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.attention import flash_attention, mha_ref
+from repro.core.machine import TPU_V5E
+from repro.kernels.attention import flash_attention, mha_ref, select_blocks
 from repro.kernels.lbm_d3q15 import init_fields, lbm_step, lbm_step_ref
 from repro.kernels.stencil25 import select_block, stencil25, stencil25_ref
 
@@ -41,8 +42,20 @@ def test_stencil_ranges(r):
     np.testing.assert_allclose(out[sl], ref[sl], rtol=3e-5, atol=3e-5)
 
 
+def test_interpret_mode_needs_an_explicit_block():
+    """Interpret mode runs on no chip, so there is no machine to select for."""
+    src = jnp.zeros((16, 16, 32), jnp.float32)
+    with pytest.raises(ValueError, match="TPUMachine"):
+        stencil25(src, r=4, interpret=True)
+
+
+def test_attention_selection_raises_without_candidates():
+    with pytest.raises(ValueError, match="divide sequence length 64"):
+        select_blocks(1, 2, 2, 64, 32, machine=TPU_V5E)
+
+
 def test_stencil_estimator_selection_valid():
-    blk, est = select_block((64, 64, 128), r=4)
+    blk, est = select_block((64, 64, 128), r=4, machine=TPU_V5E)
     assert est.feasible
     assert est.vmem_bytes < 100 * 2**20
     src = jnp.asarray(RNG.normal(size=(64, 64, 128)), jnp.float32)
@@ -120,6 +133,6 @@ def test_wkv_estimator_matches_dryrun_finding():
     empirically (L=64 for the rwkv6 production shape) — the paper's core thesis."""
     from repro.kernels.wkv import select_chunk
 
-    L, est = select_chunk(BH=64, S=4096, K=64)
+    L, est = select_chunk(BH=64, S=4096, K=64, machine=TPU_V5E)
     assert L == 64
     assert est.feasible

@@ -64,6 +64,27 @@ def test_decode_matches_forward(arch_id):
     )
 
 
+@pytest.mark.parametrize("arch_id", ["olmo-1b", "zamba2-7b"])
+def test_cached_prefill_matches_forward(arch_id):
+    """A multi-token prefill through the cache (the serve engine's path) gives
+    every prompt token its own position, then keeps decoding in step."""
+    cfg = get_arch(arch_id).smoke()
+    model = build_model(cfg)
+    params = init_params(model.blueprint(), RNG)
+    B, S = 2, 8
+    tokens = jax.random.randint(RNG, (B, S + 1), 0, cfg.vocab)
+    logits_full, _ = model.forward(params, tokens)
+    cache = model.init_cache(B, 16)
+    prefill, cache = model.decode_step(params, cache, tokens[:, :S])
+    np.testing.assert_allclose(
+        np.asarray(prefill), np.asarray(logits_full[:, :S]), rtol=2e-3, atol=2e-3
+    )
+    step, _ = model.decode_step(params, cache, tokens[:, S:])
+    np.testing.assert_allclose(
+        np.asarray(step[:, 0]), np.asarray(logits_full[:, S]), rtol=2e-3, atol=2e-3
+    )
+
+
 def test_train_step_decreases_loss():
     """A few steps on the structured synthetic data must reduce loss (learnable
     Markov structure — data/pipeline.py)."""

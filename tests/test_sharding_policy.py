@@ -15,8 +15,8 @@ from repro.models.params import param_pspecs
 from repro.train.sharding import batch_pspecs, cache_pspecs, rules_for_mesh
 
 MESHES = {
-    "single": AbstractMesh((("data", 16), ("model", 16))),
-    "multi": AbstractMesh((("pod", 2), ("data", 16), ("model", 16))),
+    "single": AbstractMesh((16, 16), ("data", "model")),
+    "multi": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 

@@ -1,0 +1,134 @@
+"""Compile the Pallas kernels for a TPU v5e that is described, not attached.
+
+Nothing runs: the chip's own compiler accepts or refuses each kernel, which is
+what interpret-mode tests cannot show (VMEM limits, Mosaic's block-shape rule,
+ops Mosaic cannot lower).  Shapes are the ones ``chip_smoke.py`` runs.  The
+topology is described inside a fixture, never at import: only one process at
+a time may load the TPU library.
+"""
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import tpu_estimator as te
+from repro.core.machine import tpu_machine
+from repro.kernels.attention import select_blocks
+from repro.kernels.attention.kernel import flash_attention_pallas
+from repro.kernels.lbm_d3q15 import config_space as lbm_space
+from repro.kernels.lbm_d3q15 import select_block as lbm_select
+from repro.kernels.lbm_d3q15.kernel import lbm_step_pallas
+from repro.kernels.stencil25 import config_space as stencil_space
+from repro.kernels.stencil25 import select_block as stencil_select
+from repro.kernels.stencil25.kernel import stencil25_pallas
+from repro.kernels.wkv import select_chunk
+from repro.kernels.wkv.kernel import wkv_pallas
+
+STENCIL = (256, 256, 512)  # r=4, f32
+LBM = (128, 128, 128)  # f32
+ATTN = (4, 32, 8, 8192, 128)  # b, hq, hkv, s, d; bf16
+WKV = (64, 4096, 64)  # BH, S, K; f32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler installed here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """(shape -> ShapeDtypeStruct on one described chip, its TPUMachine), with
+    the persistent compilation cache off: entries compiled for a described
+    chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def struct(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    yield struct, tpu_machine(topo.devices[0].device_kind)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compiles(fn, *structs) -> bool:
+    """True when the chip's compiler accepts ``fn``; False when it refuses the
+    kernel (block-shape rule: ValueError; VMEM overflow: JaxRuntimeError)."""
+    try:
+        jax.jit(fn).lower(*structs).compile()
+    except (ValueError, jax.errors.JaxRuntimeError):
+        return False
+    return True
+
+
+def _stencil(struct, machine, block):
+    return _compiles(
+        lambda x: stencil25_pallas(x, r=4, block=block, vmem_limit_bytes=machine.vmem_usable),
+        struct(STENCIL),
+    )
+
+
+def _lbm(struct, machine, block):
+    nz, ny, nx = LBM
+    return _compiles(
+        lambda f, p, v: lbm_step_pallas(f, p, v, block=block, vmem_limit_bytes=machine.vmem_usable),
+        struct((15, nz, ny, nx)), struct(LBM), struct((3, nz, ny, nx)),
+    )
+
+
+def test_device_kind_resolves_to_v5e(chip):
+    _, machine = chip
+    assert machine.name == "tpu-v5e"
+
+
+@pytest.mark.parametrize("cfg", stencil_space(STENCIL, 4, 32), ids=lambda c: c.name)
+def test_stencil25_candidate_compiles_iff_feasible(chip, cfg):
+    struct, machine = chip
+    assert _stencil(struct, machine, cfg.meta["block"]) == te.estimate(cfg, machine).feasible
+
+
+@pytest.mark.parametrize("cfg", lbm_space(LBM, 32), ids=lambda c: c.name)
+def test_lbm_candidate_compiles_iff_feasible(chip, cfg):
+    struct, machine = chip
+    assert _lbm(struct, machine, cfg.meta["block"]) == te.estimate(cfg, machine).feasible
+
+
+@pytest.mark.parametrize("kernel", ["stencil25", "lbm_d3q15", "attention", "wkv"])
+def test_estimator_pick_compiles(chip, kernel):
+    struct, machine = chip
+    limit = machine.vmem_usable
+    if kernel == "stencil25":
+        pick, _ = stencil_select(STENCIL, 4, jnp.float32, machine=machine)
+        assert _stencil(struct, machine, pick)
+    elif kernel == "lbm_d3q15":
+        pick, _ = lbm_select(LBM, jnp.float32, machine=machine)
+        assert _lbm(struct, machine, pick)
+    elif kernel == "attention":
+        b, hq, hkv, s, d = ATTN
+        (bq, bkv), _ = select_blocks(b, hq, hkv, s, d, jnp.bfloat16, True, machine=machine)
+        kv = struct((b, hkv, s, d), jnp.bfloat16)
+        assert _compiles(
+            lambda q, k, v: flash_attention_pallas(
+                q, k, v, block_q=bq, block_kv=bkv, vmem_limit_bytes=limit
+            ),
+            struct((b, hq, s, d), jnp.bfloat16), kv, kv,
+        )
+    else:
+        BH, S, K = WKV
+        chunk, _ = select_chunk(BH, S, K, machine=machine)
+        a = struct(WKV)
+        assert _compiles(
+            lambda r, k, v, w, u: wkv_pallas(r, k, v, w, u, chunk=chunk, vmem_limit_bytes=limit),
+            a, a, a, a, struct((K,)),
+        )

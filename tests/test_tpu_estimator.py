@@ -8,7 +8,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import tpu_estimator as te
-from repro.core.machine import TPU_V5E
+from repro.core.machine import TPU_V5E, device_machine, tpu_machine
 
 
 def _matmul_cfg(M, N, K, bm, bn, bk, bits=16):
@@ -47,7 +47,7 @@ def test_vmem_gate():
     est = te.estimate(cfg)
     assert not est.feasible
     with pytest.raises(ValueError):
-        te.select_config([cfg])
+        te.select_config([cfg], TPU_V5E)
 
 
 def test_ranking_prefers_feasible_and_fast():
@@ -85,3 +85,42 @@ def test_layout_efficiency_penalizes_ragged_lanes():
     eb = te.estimate(bad)
     assert eg.layout_efficiency == 1.0
     assert eb.layout_efficiency < 0.9
+
+
+@pytest.mark.parametrize(
+    "block,index_map,feasible",
+    [
+        ((8, 128), lambda i, j: (i, j), True),
+        ((4, 128), lambda i, j: (i, j), False),  # sublane dim moves, not a multiple of 8
+        ((8, 100), lambda i, j: (i, j), False),  # lane dim moves, not a multiple of 128
+        ((4, 100), lambda i, j: (0, 0), True),  # never moves: spans the array
+        ((4, 128), lambda i, j: (1, j), False),  # a fixed block that is not the first
+    ],
+)
+def test_tiling_rule_gate(block, index_map, feasible):
+    """Mosaic's block-shape rule is a hard gate, like VMEM."""
+    cfg = te.PallasConfig("t", (4, 4), (te.BlockAccess("x", block, index_map, 32),), 0.0)
+    est = te.estimate(cfg, TPU_V5E)
+    assert est.feasible == feasible
+    if not feasible:
+        assert est.limiter == "TILING" and est.misaligned == ("x",)
+
+
+def test_select_config_needs_a_machine():
+    cfg = _matmul_cfg(1024, 1024, 1024, 256, 256, 256)
+    with pytest.raises(ValueError, match="TPUMachine"):
+        te.select_config([cfg], None)
+
+
+def test_device_kind_lookup():
+    assert tpu_machine("TPU v5 lite") is TPU_V5E
+    with pytest.raises(KeyError, match="TPU v4"):
+        tpu_machine("TPU v4")
+
+
+def test_device_machine_refuses_a_cpu():
+    jax = pytest.importorskip("jax")
+    if jax.devices()[0].platform == "tpu":
+        pytest.skip("runs where JAX's device is not a TPU")
+    with pytest.raises(RuntimeError, match="no TPU"):
+        device_machine()
