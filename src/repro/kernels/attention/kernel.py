@@ -124,5 +124,6 @@ def flash_attention_pallas(
         ],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, hq, sq, d)

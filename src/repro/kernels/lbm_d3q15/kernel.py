@@ -150,4 +150,5 @@ def lbm_step_pallas(
         ),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        name="lbm_step",
     )(*([fp] * 9 + [pp] * 9 + [vp]))

@@ -1,13 +1,11 @@
 """jit'd wrapper + estimator-guided block selection for the LBM kernel."""
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
 from ...core.machine import TPUMachine, device_machine
+from ..entry import entry_point, timed_pick
 from .kernel import lbm_step_pallas
 from .ref import init_fields, lbm_step_ref
 
@@ -79,7 +77,7 @@ def select_block(
     return cfg.meta["block"], est
 
 
-@functools.partial(jax.jit, static_argnames=("tau", "width", "block", "interpret"))
+@entry_point(static_argnames=("tau", "width", "block", "interpret"))
 def lbm_step(
     f: jnp.ndarray,
     phase: jnp.ndarray,
@@ -89,10 +87,11 @@ def lbm_step(
     block: tuple[int, int] | None = None,
     interpret: bool = False,
 ):
-    """One LB step; block and VMEM limit as in :func:`stencil25.ops.stencil25`."""
+    """One LB step; block, VMEM limit and spans (``lbm_step.call``,
+    ``lbm_step.pick``) as in :func:`stencil25.ops.stencil25`."""
     machine = None if interpret else device_machine()
     if block is None:
-        block, _ = select_block(f.shape[1:], f.dtype, machine=machine)
+        block, _ = timed_pick("lbm_step", select_block, f.shape[1:], f.dtype, machine=machine)
     return lbm_step_pallas(
         f, phase, vel, tau=tau, width=width, block=block, interpret=interpret,
         vmem_limit_bytes=None if machine is None else machine.vmem_usable,

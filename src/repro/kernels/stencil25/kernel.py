@@ -90,4 +90,5 @@ def stencil25_pallas(
         out_shape=jax.ShapeDtypeStruct((nz, ny, nx), src.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        name="stencil25",
     )(*([padded] * 9))

@@ -1,13 +1,11 @@
 """jit'd public wrapper for the stencil kernel + estimator-guided block selection."""
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
 from ...core.machine import TPUMachine, device_machine
+from ..entry import entry_point, timed_pick
 from .kernel import stencil25_pallas
 from .ref import stencil25_ref
 
@@ -77,7 +75,7 @@ def select_block(
     return cfg.meta["block"], est
 
 
-@functools.partial(jax.jit, static_argnames=("r", "block", "interpret"))
+@entry_point(static_argnames=("r", "block", "interpret"))
 def stencil25(
     src: jnp.ndarray,
     r: int = 4,
@@ -88,11 +86,12 @@ def stencil25(
 
     On the chip the block is selected for, and compiled under the VMEM limit
     of, :func:`device_machine`; ``interpret=True`` runs on no chip and needs
-    an explicit ``block``.
+    an explicit ``block``.  Each call runs in the span ``stencil25.call``, the
+    pick in ``stencil25.pick`` (see :mod:`repro.kernels.entry`).
     """
     machine = None if interpret else device_machine()
     if block is None:
-        block, _ = select_block(src.shape, r, src.dtype, machine=machine)
+        block, _ = timed_pick("stencil25", select_block, src.shape, r, src.dtype, machine=machine)
     return stencil25_pallas(
         src, r=r, block=block, interpret=interpret,
         vmem_limit_bytes=None if machine is None else machine.vmem_usable,

@@ -93,4 +93,5 @@ def wkv_pallas(
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
+        name="wkv",
     )(r, k, v, wlog, u2)

@@ -14,7 +14,8 @@ Design constraints, in order:
   :func:`span` is one small-object allocation plus two ``perf_counter`` calls
   (the duration is still measured, because ``SweepStats.wall_s`` is defined as
   the duration of the sweep's span — the trace and the stats agree by
-  construction).  Spans are phase/batch granular, never per-config, so the
+  construction), and, once JAX's profiler is loaded, one ``is_enabled`` check
+  of it.  Spans are phase/batch granular, never per-config, so the
   disabled cost on a full sweep is well under the 2% budget
   (``tests/test_obs.py`` asserts it).
 * **Process-pool aggregation.**  Pool workers cannot append to the parent's
@@ -25,6 +26,12 @@ Design constraints, in order:
   their own ``pid``, so Perfetto shows one lane per worker process.
 * **Zero dependencies.**  Stdlib only; importable from every layer (frontend,
   core, explore) without cycles.
+* **One span, two sinks.**  Once ``jax.profiler`` has been imported by someone
+  else, each span opened while a profiler session traces also opens a
+  ``jax.profiler.TraceAnnotation`` of the same name, so the session records
+  it on the host plane of its trace, on the same clock as the device ops.  The annotation carries the
+  name alone (attributes stay on the Chrome-trace event): the profiler folds
+  keyword metadata into the event name, and trace readers match by name.
 
 Usage::
 
@@ -41,6 +48,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -52,6 +60,7 @@ __all__ = [
     "disable",
     "enable",
     "export_events",
+    "recording",
     "span",
     "validate_chrome_trace",
 ]
@@ -59,18 +68,33 @@ __all__ = [
 # process-global tracer; None = disabled (the common case, checked per span)
 _tracer: Tracer | None = None
 _lock = threading.Lock()
+# jax.profiler.TraceAnnotation, once some other layer has imported JAX's profiler
+_annotation_class = None
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is tracing,
+    else None.  Never imports JAX."""
+    global _annotation_class
+    if _annotation_class is None:
+        _annotation_class = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+        if _annotation_class is None:
+            return None
+    return _annotation_class if _annotation_class.is_enabled() else None
 
 
 class Span:
     """One timed region.  Always measures its duration (``duration_s`` after
-    exit); records a Chrome-trace event only when a tracer is enabled."""
+    exit); records a Chrome-trace event only when a tracer is enabled, and a
+    profiler event only while a JAX profiler session is tracing."""
 
-    __slots__ = ("name", "args", "t0", "duration_s", "_tracer")
+    __slots__ = ("name", "args", "t0", "duration_s", "_tracer", "_annotation")
 
     def __init__(self, name: str, tracer: Tracer | None, args: dict):
         self.name = name
         self.args = args
         self._tracer = tracer
+        self._annotation = None
         self.duration_s = 0.0
         self.t0 = 0.0
 
@@ -79,12 +103,19 @@ class Span:
         self.args.update(attrs)
 
     def __enter__(self) -> Span:
+        annotation = _profiler_annotation()
+        if annotation is not None:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         t1 = time.perf_counter()
         self.duration_s = t1 - self.t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         if self._tracer is not None:
             self._tracer._record(self.name, self.t0, self.duration_s, self.args)
 
@@ -117,20 +148,6 @@ class Tracer:
             ev["args"] = dict(args)
         with self._elock:
             self.events.append(ev)
-
-    def counter(self, name: str, value: float, **series: float) -> None:
-        """Emit a Chrome-trace counter sample (rendered as a track in Perfetto)."""
-        with self._elock:
-            self.events.append(
-                {
-                    "name": name,
-                    "ph": "C",
-                    "ts": (time.perf_counter() - self.epoch_perf) * 1e6,
-                    "pid": self.pid,
-                    "tid": 0,
-                    "args": {**series} if series else {"value": value},
-                }
-            )
 
     def absorb(self, payload: dict) -> None:
         """Merge :func:`export_events` output from another process, shifting its
@@ -193,6 +210,13 @@ def disable() -> None:
 def active() -> Tracer | None:
     """The enabled tracer, or None when tracing is off."""
     return _tracer
+
+
+def recording() -> bool:
+    """Whether a span opened now is recorded anywhere: by the enabled tracer
+    or by a JAX profiler session.  A hot caller that needs no duration skips
+    the span when it is not."""
+    return _tracer is not None or _profiler_annotation() is not None
 
 
 def span(name: str, **args: Any) -> Span:

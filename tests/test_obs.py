@@ -142,13 +142,93 @@ def test_trace_export_is_loadable_json(tmp_path):
     tracer = trace.enable()
     with trace.span("phase"):
         pass
-    tracer.counter("cands", 3)
     path = tmp_path / "trace.json"
     n = tracer.export(path)
     doc = json.loads(path.read_text())
     assert len(doc["traceEvents"]) == n
     assert doc["displayTimeUnit"] == "ms"
     assert trace.validate_chrome_trace(doc) == []
+
+
+def _profiled_host_events(log_dir) -> set[str]:
+    """Names of every host-plane event in the xplane a profiler session wrote
+    under ``log_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = Path(log_dir).glob("plugins/profile/*/*.xplane.pb")
+    data = ProfileData.from_file(str(path))
+    return {e.name for plane in data.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+@pytest.mark.parametrize("tracer_on", [False, True], ids=["tracer_off", "tracer_on"])
+def test_span_lands_in_the_profiler_trace(tmp_path, tracer_on):
+    """One span, both sinks: the profiler records it by name whether or not
+    the Chrome-trace tracer is enabled, and the tracer still records it."""
+    import jax
+
+    tracer = trace.enable() if tracer_on else None
+    with jax.profiler.trace(str(tmp_path)):
+        with trace.span("x", size=3):
+            pass
+    assert "x" in _profiled_host_events(tmp_path)
+    if tracer_on:
+        assert tracer.span_names() == {"x"}
+        assert tracer.events[0]["args"] == {"size": 3}
+
+
+def test_recording_follows_the_tracer_and_the_profiler(tmp_path):
+    import jax
+
+    assert not trace.recording()
+    with jax.profiler.trace(str(tmp_path)):
+        assert trace.recording()
+    assert not trace.recording()
+    trace.enable()
+    assert trace.recording()
+
+
+@pytest.mark.parametrize("sink", ["profiler", "tracer"])
+def test_entry_point_call_span_reaches_each_sink(tmp_path, sink):
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.stencil25 import stencil25
+
+    src = jnp.ones((16, 16, 128), jnp.float32)
+    if sink == "profiler":
+        with jax.profiler.trace(str(tmp_path)):
+            jax.block_until_ready(stencil25(src, block=(8, 8), interpret=True))
+        assert "stencil25.call" in _profiled_host_events(tmp_path)
+    else:
+        tracer = trace.enable()
+        jax.block_until_ready(stencil25(src, block=(8, 8), interpret=True))
+        assert "stencil25.call" in tracer.span_names()
+
+
+def test_entry_pick_is_counted_once_per_trace_and_select_block_is_not(monkeypatch):
+    """The pick inside the jit trace of ``stencil25`` observes
+    ``estimator.pick_seconds{entry=stencil25}``; a direct ``select_block``
+    (as the benchmark's outside pick makes) does not."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.machine import tpu_machine
+    from repro.kernels.stencil25 import ops, select_block, stencil25
+
+    machine = tpu_machine("TPU v5 lite")
+    monkeypatch.setattr(ops, "device_machine", lambda: machine)
+    series = "estimator.pick_seconds{entry=stencil25}"
+
+    def count():
+        return metrics.snapshot()["histograms"].get(series, {"count": 0})["count"]
+
+    shape = (48, 40, 256)  # traced by no other test of this process
+    before = count()
+    stencil25.trace(jax.ShapeDtypeStruct(shape, jnp.float32))
+    assert count() == before + 1
+    select_block(shape, 4, jnp.float32, machine=machine)
+    assert count() == before + 1
 
 
 def test_pool_sweep_aggregates_worker_spans():

@@ -8,6 +8,11 @@ a time may load the TPU library.
 """
 from __future__ import annotations
 
+import json
+import re
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -26,6 +31,7 @@ from repro.kernels.stencil25.kernel import stencil25_pallas
 from repro.kernels.wkv import select_chunk
 from repro.kernels.wkv.kernel import wkv_pallas
 
+CHIP_BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 STENCIL = (256, 256, 512)  # r=4, f32
 LBM = (128, 128, 128)  # f32
 ATTN = (4, 32, 8, 8192, 128)  # b, hq, hkv, s, d; bf16
@@ -132,3 +138,72 @@ def test_estimator_pick_compiles(chip, kernel):
             lambda r, k, v, w, u: wkv_pallas(r, k, v, w, u, chunk=chunk, vmem_limit_bytes=limit),
             a, a, a, a, struct((K,)),
         )
+
+
+def _kernel_instructions(lowered) -> list[str]:
+    """The compiled ``tpu_custom_call`` instructions of ``lowered``, each cut
+    as the chip benchmark's trace reduction names a device op."""
+    sys.path.insert(0, str(CHIP_BENCH))
+    from trace_reduce import short_name
+
+    return [short_name(line.strip().removeprefix("ROOT "))
+            for line in lowered.compile().as_text().splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _in_scope(fn, scoped: bool):
+    def call(*args):
+        if not scoped:
+            return fn(*args)
+        with jax.named_scope("xpad"):
+            return fn(*args)
+    return call
+
+
+@pytest.mark.parametrize("how", ["entry", "entry_in_scope", "kernel_in_scope"])
+@pytest.mark.parametrize("config", ["stencil25-r4-f32", "lbm-d3q15-f32"])
+def test_entry_kernel_matches_the_benchmark_kernel_pattern(chip, monkeypatch, config, how):
+    """The chip benchmark finds the kernel by ``kernel_pattern``.  The kernel
+    keeps its name (``pallas_call(name=...)``) when the entry is called inside
+    an outer scope, and when the kernel itself is, in a jit of another name."""
+    from repro.kernels.lbm_d3q15 import lbm_step
+    from repro.kernels.lbm_d3q15 import ops as lbm_ops
+    from repro.kernels.stencil25 import ops as stencil_ops
+    from repro.kernels.stencil25 import stencil25
+
+    struct, machine = chip
+    spec = json.loads((CHIP_BENCH / "configs" / config / "config.json").read_text())
+    limit = machine.vmem_usable
+    if spec["kernel"] == "stencil25":
+        monkeypatch.setattr(stencil_ops, "device_machine", lambda: machine)
+        fn = (lambda x: stencil25_pallas(x, r=4, block=(8, 8), vmem_limit_bytes=limit)
+              ) if how == "kernel_in_scope" else (lambda x: stencil25(x, r=4))
+        args = (struct(STENCIL),)
+    else:
+        monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
+        fn = (lambda f, p, v: lbm_step_pallas(f, p, v, block=(8, 8), vmem_limit_bytes=limit)
+              ) if how == "kernel_in_scope" else lbm_step
+        nz, ny, nx = LBM
+        args = struct((15, nz, ny, nx)), struct(LBM), struct((3, nz, ny, nx))
+    (kernel,) = _kernel_instructions(jax.jit(_in_scope(fn, how != "entry")).lower(*args))
+    assert re.search(spec["kernel_pattern"], kernel), kernel
+
+
+@pytest.mark.parametrize("name", ["flash_attention", "wkv"])
+def test_model_kernel_instruction_is_named_by_its_pallas_call(chip, name):
+    """Called inside a scope, in a jit of another name, the kernel still
+    compiles to an instruction named after its ``pallas_call(name=...)``."""
+    struct, machine = chip
+    limit = machine.vmem_usable
+    if name == "flash_attention":
+        b, hq, hkv, s, d = ATTN
+        kv = struct((b, hkv, s, d), jnp.bfloat16)
+        fn = lambda q, k, v: flash_attention_pallas(  # noqa: E731
+            q, k, v, block_q=512, block_kv=512, vmem_limit_bytes=limit)
+        args = struct((b, hq, s, d), jnp.bfloat16), kv, kv
+    else:
+        fn = lambda r, k, v, w, u: wkv_pallas(r, k, v, w, u, chunk=64, vmem_limit_bytes=limit)  # noqa: E731
+        a = struct(WKV)
+        args = a, a, a, a, struct((WKV[2],))
+    (kernel,) = _kernel_instructions(jax.jit(_in_scope(fn, True)).lower(*args))
+    assert re.match(rf"^%{name}(\.\d+)? custom-call", kernel), kernel
