@@ -2,11 +2,13 @@
 
 TPU adaptation (DESIGN.md §2): instead of CUDA thread blocks, the configuration
 space is the BlockSpec tiling.  The grid is 2D over (z, y) tiles; x (the lane
-dimension) stays whole per tile and is ghost-padded by r.  Halo exchange in z/y is
-expressed with nine overlapping input BlockSpecs (the 3x3 neighborhood of the
-center tile) — the redundant neighbor fetches are exactly the V_red the paper's
-estimator models, and `ops.select_block()` picks (bz, by) by ranking candidates
-with `core.tpu_estimator` instead of autotuning.
+dimension) stays whole per tile and is ghost-padded by r.  The star reads no
+(z, y) corner, so the z/y halo is five input BlockSpecs over the padded array
+(:func:`input_blocks`): the centre tile, a thin strip on each z side and a
+thin strip on each y side.  The strips' overlap with the neighbour tiles is
+the V_red the paper's estimator models; ``ops.config_space`` describes the
+same five blocks, and ``ops.select_block()`` picks (bz, by) by ranking
+candidates with `core.tpu_estimator` instead of autotuning.
 """
 from __future__ import annotations
 
@@ -19,28 +21,98 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .ref import star_offsets, star_weights_np
 
-NEIGHBORS = [(dz, dy) for dz in (-1, 0, 1) for dy in (-1, 0, 1)]
+INPUTS = ("centre", "z_lo", "z_hi", "y_lo", "y_hi")
 
 
-def _stencil_kernel(*refs, r: int, bz: int, by: int, nx: int, weights):
-    """refs = 9 input tiles (3x3 neighborhood, each (bz, by, nxp)) + out ref."""
-    out_ref = refs[-1]
-    tiles = refs[:-1]
-    # assemble the (3bz, 3by, nxp) neighborhood, then crop to the halo window
-    rows = []
-    for iz in range(3):
-        row = jnp.concatenate(
-            [tiles[iz * 3 + iy][...] for iy in range(3)], axis=1
-        )
-        rows.append(row)
-    vol = jnp.concatenate(rows, axis=0)  # (3bz, 3by, nxp)
-    win = vol[bz - r : 2 * bz + r, by - r : 2 * by + r, :]  # (bz+2r, by+2r, nxp)
+def strip_heights(r: int, block: tuple[int, int], dtype_bits: int) -> tuple[int, int]:
+    """(hz, hy): the z and y extents of the halo strips of a (bz, by) tile.
+
+    ``hz`` is the smallest divisor of ``bz`` that holds ``r`` planes.  ``hy``
+    is the smallest divisor of ``by`` that holds ``r`` rows and is a multiple
+    of the dtype's sublane tile (8 rows of 32 bits, 16 of 16 bits), so the y
+    strips keep Mosaic's block-shape rule; without one it is ``by``, a whole
+    neighbour tile.
+    """
+    bz, by = block
+    sublanes = 8 * max(1, 32 // dtype_bits)
+    hz = min(d for d in range(r, bz + 1) if bz % d == 0)
+    hy = min(
+        (d for d in range(r, by + 1) if by % d == 0 and d % sublanes == 0),
+        default=by,
+    )
+    return hz, hy
+
+
+def input_blocks(r: int, block: tuple[int, int], nx: int, dtype_bits: int):
+    """``(name, block_shape, index_map)`` of the kernel's five inputs over the
+    x-padded (nz, ny, nx + 2r) array, in :data:`INPUTS` order.
+
+    The index maps are the interior ones: the strips sit in the blocks of
+    their own height just outside the centre tile.  The kernel clamps them to
+    the grid; ``ops.config_space`` gives them to the estimator as they are.
+    """
+    bz, by = block
+    hz, hy = strip_heights(r, block, dtype_bits)
+    kz, ky = bz // hz, by // hy
+    nxp = nx + 2 * r
+    maps = (
+        ((bz, by, nxp), lambda i, j: (i, j, 0)),
+        ((hz, by, nxp), lambda i, j: (i * kz - 1, j, 0)),
+        ((hz, by, nxp), lambda i, j: ((i + 1) * kz, j, 0)),
+        ((bz, hy, nxp), lambda i, j: (i, j * ky - 1, 0)),
+        ((bz, hy, nxp), lambda i, j: (i, (j + 1) * ky, 0)),
+    )
+    return tuple((name, shape, fn) for name, (shape, fn) in zip(INPUTS, maps))
+
+
+def _stencil_kernel(c_ref, zlo_ref, zhi_ref, ylo_ref, yhi_ref, out_ref, *, r, nx, weights):
+    """One (bz, by, nx) output tile from its centre tile and four halo strips.
+
+    z offsets read a (bz + 2r)-plane window: the last r planes of the lower z
+    strip, the centre, the first r of the upper.  y offsets read the centre
+    between the whole y strips, which keeps the concatenation on sublane
+    tiles; only the r rows of each strip next to the centre are read.  x
+    offsets read the centre's ghost-padded lanes.  Terms are summed in
+    :func:`star_offsets` order, as the reference sums them.
+    """
+    bz, by, _ = c_ref.shape
+    hz, hy = zlo_ref.shape[0], ylo_ref.shape[1]
+    c = c_ref[...]
+    zwin = jnp.concatenate([zlo_ref[hz - r :], c, zhi_ref[:r]], axis=0)
+    ywin = jnp.concatenate([ylo_ref[...], c, yhi_ref[...]], axis=1)
     acc = jnp.zeros((bz, by, nx), dtype=out_ref.dtype)
     for k, (dz, dy, dx) in enumerate(star_offsets(r)):
-        acc = acc + weights[k] * win[
-            r + dz : r + dz + bz, r + dy : r + dy + by, r + dx : r + dx + nx
-        ]
+        if dz:
+            term = zwin[r + dz : r + dz + bz, :, r : r + nx]
+        elif dy:
+            term = ywin[:, hy + dy : hy + dy + by, r : r + nx]
+        else:
+            term = c[:, :, r + dx : r + dx + nx]
+        acc = acc + weights[k] * term
     out_ref[...] = acc
+
+
+def block_specs(shape: tuple[int, int, int], r: int, block: tuple[int, int], dtype_bits: int):
+    """``(in_specs, out_spec)`` of the kernel on an (nz, ny, nx) field: the five
+    :func:`input_blocks` over the x-padded array, each block index clamped to
+    the array's blocks of that shape, and the (bz, by, nx) output tile."""
+    nz, ny, nx = shape
+    padded_shape = (nz, ny, nx + 2 * r)
+
+    def clamped(index_map, block_shape):
+        def clamped_map(i, j):
+            return tuple(
+                jnp.clip(b, 0, n // s - 1)
+                for b, s, n in zip(index_map(i, j), block_shape, padded_shape)
+            )
+
+        return clamped_map
+
+    in_specs = [
+        pl.BlockSpec(block_shape, clamped(index_map, block_shape))
+        for _, block_shape, index_map in input_blocks(r, block, nx, dtype_bits)
+    ]
+    return in_specs, pl.BlockSpec((*block, nx), lambda i, j: (i, j, 0))
 
 
 def stencil25_pallas(
@@ -53,7 +125,7 @@ def stencil25_pallas(
     """Apply the stencil to ``src`` (nz, ny, nx).
 
     Interior [r:-r, r:-r, r:-r] matches :func:`ref.stencil25_ref`; cells closer to
-    the global boundary than r use clamped tile indices and are not defined.
+    the global boundary than r read clamped strips and are not defined.
     """
     nz, ny, nx = src.shape
     bz, by = block
@@ -61,34 +133,18 @@ def stencil25_pallas(
         raise ValueError(f"block {block} must be >= r={r} in z and y")
     if nz % bz or ny % by:
         raise ValueError(f"grid {src.shape} not divisible by block {block}")
-    nzb, nyb = nz // bz, ny // by
-    nxp = nx + 2 * r
     padded = jnp.pad(src, ((0, 0), (0, 0), (r, r)), mode="edge")
     # weights as python floats: compile-time constants inside the kernel body
     w = tuple(float(v) for v in star_weights_np(r))
-
-    def make_index_map(dz, dy):
-        def index_map(i, j):
-            zi = jnp.clip(i + dz, 0, nzb - 1)
-            yj = jnp.clip(j + dy, 0, nyb - 1)
-            return (zi, yj, 0)
-
-        return index_map
-
-    in_specs = [
-        pl.BlockSpec((bz, by, nxp), make_index_map(dz, dy)) for dz, dy in NEIGHBORS
-    ]
-    out_spec = pl.BlockSpec((bz, by, nx), lambda i, j: (i, j, 0))
-    kernel = functools.partial(
-        _stencil_kernel, r=r, bz=bz, by=by, nx=nx, weights=w
-    )
+    in_specs, out_spec = block_specs(src.shape, r, block, src.dtype.itemsize * 8)
+    kernel = functools.partial(_stencil_kernel, r=r, nx=nx, weights=w)
     return pl.pallas_call(
         kernel,
-        grid=(nzb, nyb),
+        grid=(nz // bz, ny // by),
         in_specs=in_specs,
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((nz, ny, nx), src.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name="stencil25",
-    )(*([padded] * 9))
+    )(*([padded] * len(in_specs)))

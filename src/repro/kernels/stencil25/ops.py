@@ -6,7 +6,7 @@ import jax.numpy as jnp
 from ...core import tpu_estimator as te
 from ...core.machine import TPUMachine, device_machine
 from ..entry import entry_point, timed_pick
-from .kernel import stencil25_pallas
+from .kernel import input_blocks, stencil25_pallas
 from .ref import stencil25_ref
 
 CANDIDATE_BLOCKS = ((8, 8), (8, 16), (16, 8), (16, 16), (16, 32), (32, 16), (32, 32), (64, 8), (8, 64))
@@ -15,28 +15,22 @@ CANDIDATE_BLOCKS = ((8, 8), (8, 16), (16, 8), (16, 16), (16, 32), (32, 16), (32,
 def config_space(shape: tuple[int, int, int], r: int, dtype_bits: int):
     """Candidate PallasConfigs for `core.tpu_estimator` ranking.
 
-    Nine overlapping input tiles model the halo refetch redundancy; interior
-    (unclamped) index maps are used as the representative group (paper §III.D:
-    representative collaborative groups away from boundaries).
+    Each candidate's accesses are the kernel's own five inputs
+    (:func:`kernel.input_blocks`: the centre tile and four halo strips, whose
+    overlap with the neighbour tiles is the refetch redundancy) plus ``out``;
+    interior (unclamped) index maps are used as the representative group
+    (paper §III.D: representative collaborative groups away from boundaries).
     """
     nz, ny, nx = shape
-    nxp = nx + 2 * r
     out = []
     for bz, by in CANDIDATE_BLOCKS:
         if bz < r or by < r or nz % bz or ny % by:
             continue
-        accesses = []
-        for k, (dz, dy) in enumerate(
-            [(dz, dy) for dz in (-1, 0, 1) for dy in (-1, 0, 1)]
-        ):
-            accesses.append(
-                te.BlockAccess(
-                    name=f"in{k}",
-                    block_shape=(bz, by, nxp),
-                    index_map=(lambda dz=dz, dy=dy: (lambda i, j: (i + dz, j + dy, 0)))(),
-                    dtype_bits=dtype_bits,
-                )
-            )
+        accesses = [
+            te.BlockAccess(name=name, block_shape=block_shape, index_map=index_map,
+                           dtype_bits=dtype_bits)
+            for name, block_shape, index_map in input_blocks(r, (bz, by), nx, dtype_bits)
+        ]
         accesses.append(
             te.BlockAccess(
                 name="out",
