@@ -1,7 +1,9 @@
 """Kernel + machine registry for the exploration engine.
 
 Every explorable kernel is one *family* (``stencil25``, ``lbm_d3q15``,
-``attention``, ``wkv``) with one :class:`KernelEntry` per estimation backend:
+``attention``, ``wkv``, ``lbm_d3q27``) with one :class:`KernelEntry` per
+estimation backend it has (``lbm_d3q27``, the two-phase solver's
+hydrodynamic kernel, has a TPU entry only):
 
 * **gpu** — the entry declares an IR-producing builder
   (``build_ir: (**config) -> AccessIR``); the engine lowers the IR through
@@ -201,6 +203,12 @@ def _tpu_lbm_configs():
     return config_space((128, 128, 128), dtype_bits=32)
 
 
+def _tpu_lbm27_configs():
+    from ..kernels.lbm_d3q27.ops import config_space
+
+    return config_space((128, 128, 128), dtype_bits=32)
+
+
 @dataclass(frozen=True)
 class KernelEntry:
     """One explorable (kernel family, backend) pair.
@@ -286,6 +294,14 @@ KERNELS: dict[str, KernelEntry] = {
         backend="tpu",
         describe="LBM D3Q15 Pallas block space on TPU v5e",
         tpu_configs=_tpu_lbm_configs,
+        default_machine="TPUv5e",
+    ),
+    "lbm_d3q27_tpu": KernelEntry(
+        name="lbm_d3q27_tpu",
+        family="lbm_d3q27",
+        backend="tpu",
+        describe="two-phase LBM D3Q27 hydrodynamic Pallas block space on TPU v5e",
+        tpu_configs=_tpu_lbm27_configs,
         default_machine="TPUv5e",
     ),
     "attention_tpu": KernelEntry(

@@ -41,6 +41,13 @@ def test_registry_families_and_backend_resolution():
         assert gpu.backend == "gpu" and gpu.build_ir is not None
         assert tpu.backend == "tpu" and tpu.tpu_configs is not None
         assert gpu.family == tpu.family == family
+    # a TPU-only family: the two-phase solver's D3Q27 hydrodynamic kernel
+    for family in ("lbm_d3q27",):
+        tpu = get_kernel(f"{family}_tpu", backend="tpu")
+        assert tpu.family == family and tpu.tpu_configs is not None
+        assert len(tpu.tpu_configs()) >= 3
+        with pytest.raises(KeyError, match="no 'gpu' backend"):
+            get_kernel(f"{family}_tpu", backend="gpu")
     # tpu-named entries resolve back to the gpu variant and vice versa
     assert get_kernel("attention_tpu", backend="gpu").name == "attention"
     assert get_kernel("wkv", backend="tpu").name == "wkv_tpu"

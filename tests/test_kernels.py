@@ -1,6 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracles."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,6 +10,18 @@ from repro.core import tpu_estimator as te
 from repro.core.machine import TPU_V5E
 from repro.kernels.attention import flash_attention, mha_ref, select_blocks
 from repro.kernels.lbm_d3q15 import init_fields, lbm_step, lbm_step_ref
+from repro.kernels.lbm_d3q15 import select_block as lbm_select
+from repro.kernels.lbm_d3q27 import (
+    TwoPhaseParams,
+    equilibrium,
+    hydro_step_ref,
+    twophase_step,
+    twophase_step_ref,
+)
+from repro.kernels.lbm_d3q27 import config_space as lbm27_space
+from repro.kernels.lbm_d3q27.kernel import block_specs as lbm27_block_specs
+from repro.kernels.lbm_d3q27.kernel import hydro_step_pallas
+from repro.kernels.lbm_d3q27.ref import DIRS as DIRS27
 from repro.kernels.stencil25 import config_space, select_block, stencil25, stencil25_ref
 from repro.kernels.stencil25.kernel import INPUTS as STENCIL_INPUTS
 from repro.kernels.stencil25.kernel import block_specs, strip_heights
@@ -123,6 +136,40 @@ def test_stencil_estimator_counts_strip_bytes():
     assert ranked[0] == (32, 32)
 
 
+@pytest.mark.parametrize("block_mib, feasible", [(90, True), (100, True), (102, False)])
+def test_vmem_gate_holds_the_blocks_to_vmem_usable(block_mib, feasible):
+    """The gate is the double-buffered blocks against ``vmem_usable``, the
+    limit every kernel passes the compiler: 100 MiB on v5e."""
+    rows = block_mib * 256 // 2  # f32 rows of 1024 lanes, 4 KiB each, double-buffered
+    cfg = te.PallasConfig(
+        name="gate",
+        grid=(4,),
+        accesses=(te.BlockAccess("x", (rows, 1024), lambda i: (i, 0), 32),),
+        is_matmul=False,
+    )
+    est = te.estimate(cfg, TPU_V5E)
+    assert est.vmem_bytes == block_mib * 2**20
+    assert est.feasible == feasible
+
+
+@pytest.mark.parametrize(
+    "kernel, shape, pick",
+    [
+        ("stencil25", (1024, 1024, 512), (32, 32)),  # stencil25.bulk
+        ("stencil25", (32, 32, 512), (32, 32)),  # stencil25.ensemble
+        ("lbm_d3q15", (256, 256, 256), (8, 8)),  # lbm_d3q15.bulk
+    ],
+)
+def test_estimator_picks_of_the_benchmark_cells_stand(kernel, shape, pick):
+    """The picks of the chip benchmark's accepted cells stand: adding the
+    D3Q27 kernel moved nothing the estimator shares with them."""
+    if kernel == "stencil25":
+        got, _ = select_block(shape, 4, jnp.float32, machine=TPU_V5E)
+    else:
+        got, _ = lbm_select(shape, jnp.float32, machine=TPU_V5E)
+    assert got == pick
+
+
 def test_interpret_mode_needs_an_explicit_block():
     """Interpret mode runs on no chip, so there is no machine to select for."""
     src = jnp.zeros((16, 16, 32), jnp.float32)
@@ -162,6 +209,144 @@ def test_lbm_mass_conservation():
     f, phase, vel = init_fields((16, 16, 32))
     fr, pr = lbm_step_ref(f, phase, 0.0 * vel)
     assert abs(float(pr.sum()) - float(phase.sum())) / float(phase.sum()) < 1e-3
+
+
+LBM27_SHAPES = [(16, 16, 128), (32, 32, 128)]
+LBM27_BLOCKS = [(8, 8), (16, 8), (16, 16)]
+LBM27_SHELL = (Ellipsis, slice(2, -2), slice(2, -2), slice(None))  # the kernels clamp their z/y halo
+
+
+def _lbm27_close(got, want):
+    """max |got - want| <= 1e-5 max |want|.  The kernel sums the 27-point
+    phase derivatives one axis at a time where the oracle sums term by term,
+    and multiplies by 1/rho where the oracle divides: f32 reassociation, a
+    few 1e-7 of the largest value (rho falls to rho_light, so forces over rho
+    are the field's largest terms).  A wrong neighbour, sign or weight moves
+    values by 1e-3 or more of it."""
+    got, want = np.asarray(got[LBM27_SHELL]), np.asarray(want[LBM27_SHELL])
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+def _lbm27_fields(shape, seed):
+    rng = np.random.default_rng(seed)
+    phase = jnp.asarray(rng.uniform(0.0, 1.0, shape), jnp.float32)
+    vel = jnp.asarray(0.02 * rng.normal(size=(3, *shape)), jnp.float32)
+    g = equilibrium(vel, 0.01) + jnp.asarray(1e-3 * rng.normal(size=(27, *shape)), jnp.float32)
+    return g, phase, vel
+
+
+@pytest.mark.parametrize("block", LBM27_BLOCKS)
+@pytest.mark.parametrize("shape", LBM27_SHAPES)
+def test_lbm27_kernel_allclose(shape, block):
+    """The D3Q27 kernel against the oracle on random fields: phase uniform in
+    [0, 1], so every neighbour of the 27-point derivatives differs."""
+    g, phase, vel = _lbm27_fields(shape, 27)
+    params = TwoPhaseParams()
+    go, uo = hydro_step_pallas(g, phase, vel, params, block=block, interpret=True)
+    gr, ur = jax.jit(hydro_step_ref, static_argnums=3)(g, phase, vel, params)
+    _lbm27_close(go, gr)
+    _lbm27_close(uo, ur)
+
+
+@pytest.mark.parametrize("block", LBM27_BLOCKS)
+@pytest.mark.parametrize("shape", LBM27_SHAPES)
+def test_twophase_step_allclose(shape, block):
+    """The coupled entry (D3Q15 then D3Q27, one jit) against the coupled
+    oracle from the droplet, outside the two-cell shell: the D3Q15 step
+    leaves one cell undefined and the D3Q27 kernel reads phi' one further."""
+    f, phase, vel = init_fields(shape, seed=5)
+    g = equilibrium(vel)
+    params = TwoPhaseParams()
+    out = twophase_step(f, g, phase, vel, params=params, block=block, phase_block=block,
+                        interpret=True)
+    for got, want in zip(out, jax.jit(twophase_step_ref, static_argnums=4)(f, g, phase, vel, params)):
+        _lbm27_close(got, want)
+
+
+def test_twophase_interpret_needs_both_blocks():
+    """Interpret mode runs on no chip: neither kernel's block can be picked."""
+    f, phase, vel = init_fields((16, 16, 128))
+    with pytest.raises(ValueError, match="phase_block"):
+        twophase_step(f, equilibrium(vel), phase, vel, block=(8, 8), interpret=True)
+
+
+def test_sharpening_width_gives_the_sources_collision():
+    """The D3Q15 step adds its sharpening term in full; at
+    ``sharpening_width`` it adds the sources' 1 - 1/(2 tau_phi) of the term
+    at the interface width xi, the step being affine in the term."""
+    params = TwoPhaseParams()
+    f, phase, vel = init_fields((16, 16, 32), seed=2)
+    unforced, _ = lbm_step_ref(f, phase, vel, tau=params.tau_phase, width=float("inf"))
+    full, _ = lbm_step_ref(f, phase, vel, tau=params.tau_phase, width=params.width)
+    got, _ = lbm_step_ref(f, phase, vel, tau=params.tau_phase, width=params.sharpening_width)
+    want = unforced + (1.0 - 0.5 / params.tau_phase) * (full - unforced)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-7)
+
+
+def test_twophase_droplet_holds_at_density_ratio_1000():
+    """The coupled oracle at the benchmark's parameters (density ratio 1000)
+    from a droplet under N(0, 0.01) velocity noise: 300 steps stay finite,
+    phi stays in [0, 1] to 1e-4 and the flow slow, as the chip runs it."""
+    shape = (24, 24, 24)
+    f, phase, vel = init_fields(shape, seed=11)
+    params = TwoPhaseParams()
+    state = jax.jit(lambda s: jax.lax.fori_loop(
+        0, 300, lambda _, s: twophase_step_ref(*s, params=params), s))((f, equilibrium(vel), phase, vel))
+    _, g, phase, vel = (np.asarray(a) for a in state)
+    assert np.isfinite(g).all() and np.isfinite(vel).all()
+    assert -1e-4 <= phase.min() and phase.max() <= 1.0 + 1e-4
+    assert np.abs(vel).max() < 0.1
+
+
+def test_lbm27_fluid_at_rest_stays_at_rest():
+    """One phase (phi = 1) at rest under a uniform pressure, g at equilibrium:
+    no force, nothing streams in that differs, so ten steps leave it as it was."""
+    shape = (8, 8, 16)
+    phase = jnp.ones(shape, jnp.float32)
+    vel = jnp.zeros((3, *shape), jnp.float32)
+    g0 = equilibrium(vel, 0.01)
+    g, u = g0, vel
+    for _ in range(10):
+        g, u = hydro_step_ref(g, phase, u)
+    assert float(jnp.abs(u).max()) <= 1e-7
+    assert float(jnp.abs(g - g0).max()) <= 1e-7
+
+
+def test_lbm27_momentum_is_conserved_without_force():
+    """Uniform phase (no force) on a periodic domain: streaming moves
+    momentum and collision keeps each cell's, so sum_p sum_a c_a g_a stays."""
+    shape = (8, 8, 16)
+    rng = np.random.default_rng(3)
+    phase = jnp.full(shape, 0.7, jnp.float32)
+    vel = jnp.asarray(0.02 * rng.normal(size=(3, *shape)), jnp.float32)
+    g = equilibrium(vel) + jnp.asarray(1e-3 * rng.normal(size=(27, *shape)), jnp.float32)
+    c = jnp.asarray(DIRS27, jnp.float32)  # (27, 3)
+
+    def momentum(g):
+        return jnp.einsum("ai,a...->i", c, g, precision="highest")
+
+    before = momentum(g)
+    u = vel
+    for _ in range(5):
+        g, u = hydro_step_ref(g, phase, u)
+    assert float(jnp.abs(momentum(g) - before).max()) <= 1e-6
+
+
+def test_lbm27_config_space_matches_kernel_block_specs():
+    """The estimator's candidate describes the kernel's own BlockSpecs: nine
+    class centres, six z strips, six y strips, four corner pieces; phase's
+    centre, four strips, four corners; vel's centre; then g' and u'."""
+    shape, bits = (256, 256, 256), 32
+    cfg = next(c for c in lbm27_space(shape, bits) if c.meta["block"] == (16, 16))
+    names, in_specs, out_specs = lbm27_block_specs(shape, (16, 16), bits)
+    assert len(in_specs) == 9 + 6 + 6 + 4 + 1 + 4 + 4 + 1 == len(names)
+    assert len(cfg.accesses) == len(in_specs) + 2
+    assert [a.is_output for a in cfg.accesses] == [False] * len(in_specs) + [True, True]
+    assert [a.name for a in cfg.accesses[: len(names)]] == list(names)
+    for acc, spec in zip(cfg.accesses, [*in_specs, *out_specs]):
+        assert tuple(acc.block_shape) == tuple(spec.block_shape), acc.name
+        for i, j in ((1, 1), (5, 9), (14, 14)):  # interior: no clamp applies
+            assert tuple(int(v) for v in spec.index_map(i, j)) == acc.index_map(i, j), acc.name
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -231,6 +231,43 @@ def test_entry_pick_is_counted_once_per_trace_and_select_block_is_not(monkeypatc
     assert count() == before + 1
 
 
+def test_twophase_step_opens_its_spans_and_counts_its_picks(monkeypatch):
+    """While recording, one ``twophase_step`` call opens ``twophase_step.call``
+    once; its first trace opens ``lbm_step.pick`` (the D3Q15 step's pick,
+    traced into the same jit) and ``lbm_d3q27.pick``, and observes
+    ``estimator.pick_seconds{entry=lbm_d3q27}`` once."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.machine import tpu_machine
+    from repro.kernels.lbm_d3q15 import init_fields
+    from repro.kernels.lbm_d3q15 import ops as lbm15_ops
+    from repro.kernels.lbm_d3q27 import equilibrium, ops, twophase_step
+
+    f, phase, vel = init_fields((16, 16, 128))
+    tracer = trace.enable()
+    jax.block_until_ready(twophase_step(f, equilibrium(vel), phase, vel, block=(8, 8),
+                                        phase_block=(8, 8), interpret=True))
+    assert [e["name"] for e in tracer.events].count("twophase_step.call") == 1
+
+    machine = tpu_machine("TPU v5 lite")
+    monkeypatch.setattr(ops, "device_machine", lambda: machine)
+    monkeypatch.setattr(lbm15_ops, "device_machine", lambda: machine)
+    series = "estimator.pick_seconds{entry=lbm_d3q27}"
+
+    def count():
+        return metrics.snapshot()["histograms"].get(series, {"count": 0})["count"]
+
+    shape = (40, 48, 256)  # traced by no other test of this process
+    before = count()
+    tracer = trace.enable()
+    twophase_step.trace(*(jax.ShapeDtypeStruct((n, *shape), jnp.float32) for n in (15, 27)),
+                        jax.ShapeDtypeStruct(shape, jnp.float32),
+                        jax.ShapeDtypeStruct((3, *shape), jnp.float32))
+    assert {"lbm_step.pick", "lbm_d3q27.pick"} <= tracer.span_names()
+    assert count() == before + 1
+
+
 def test_pool_sweep_aggregates_worker_spans():
     """Every pipeline phase shows up in one trace, including the per-worker
     estimate batches, and worker events keep their own pid lane."""

@@ -2,7 +2,9 @@
 
 Nothing runs: the chip's own compiler accepts or refuses each kernel, which is
 what interpret-mode tests cannot show (VMEM limits, Mosaic's block-shape rule,
-ops Mosaic cannot lower).  Shapes are the ones ``chip_smoke.py`` runs.  The
+ops Mosaic cannot lower).  Shapes are the ones ``chip_smoke.py`` runs, and
+the chip benchmark's LBM shapes: the two-phase cell's 256^3 and the LBM
+ensemble's 32x32x256.  The
 topology is described inside a fixture, never at import: only one process at
 a time may load the TPU library.
 """
@@ -25,6 +27,9 @@ from repro.kernels.attention.kernel import flash_attention_pallas
 from repro.kernels.lbm_d3q15 import config_space as lbm_space
 from repro.kernels.lbm_d3q15 import select_block as lbm_select
 from repro.kernels.lbm_d3q15.kernel import lbm_step_pallas
+from repro.kernels.lbm_d3q27 import config_space as lbm27_space
+from repro.kernels.lbm_d3q27 import select_block as lbm27_select
+from repro.kernels.lbm_d3q27.kernel import hydro_step_pallas
 from repro.kernels.stencil25 import config_space as stencil_space
 from repro.kernels.stencil25 import select_block as stencil_select
 from repro.kernels.stencil25.kernel import stencil25_pallas
@@ -34,6 +39,8 @@ from repro.kernels.wkv.kernel import wkv_pallas
 CHIP_BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 STENCIL = (256, 256, 512)  # r=4, f32
 LBM = (128, 128, 128)  # f32
+LBM27 = (256, 256, 256)  # f32, the two-phase cell's domain
+LBM_ENSEMBLE = (32, 32, 256)  # f32, the LBM ensemble cell's domain
 ATTN = (4, 32, 8, 8192, 128)  # b, hq, hkv, s, d; bf16
 WKV = (64, 4096, 64)  # BH, S, K; f32
 
@@ -85,11 +92,20 @@ def _stencil(struct, machine, block):
     )
 
 
-def _lbm(struct, machine, block):
-    nz, ny, nx = LBM
+def _lbm(struct, machine, block, shape=LBM):
+    nz, ny, nx = shape
     return _compiles(
         lambda f, p, v: lbm_step_pallas(f, p, v, block=block, vmem_limit_bytes=machine.vmem_usable),
-        struct((15, nz, ny, nx)), struct(LBM), struct((3, nz, ny, nx)),
+        struct((15, nz, ny, nx)), struct(shape), struct((3, nz, ny, nx)),
+    )
+
+
+def _lbm27(struct, machine, block):
+    nz, ny, nx = LBM27
+    return _compiles(
+        lambda g, p, v: hydro_step_pallas(g, p, v, block=block,
+                                          vmem_limit_bytes=machine.vmem_usable),
+        struct((27, nz, ny, nx)), struct(LBM27), struct((3, nz, ny, nx)),
     )
 
 
@@ -110,7 +126,20 @@ def test_lbm_candidate_compiles_iff_feasible(chip, cfg):
     assert _lbm(struct, machine, cfg.meta["block"]) == te.estimate(cfg, machine).feasible
 
 
-@pytest.mark.parametrize("kernel", ["stencil25", "lbm_d3q15", "attention", "wkv"])
+@pytest.mark.parametrize("cfg", lbm_space(LBM_ENSEMBLE, 32), ids=lambda c: c.name)
+def test_lbm_ensemble_candidate_compiles_iff_feasible(chip, cfg):
+    struct, machine = chip
+    assert (_lbm(struct, machine, cfg.meta["block"], LBM_ENSEMBLE)
+            == te.estimate(cfg, machine).feasible)
+
+
+@pytest.mark.parametrize("cfg", lbm27_space(LBM27, 32), ids=lambda c: c.name)
+def test_lbm27_candidate_compiles_iff_feasible(chip, cfg):
+    struct, machine = chip
+    assert _lbm27(struct, machine, cfg.meta["block"]) == te.estimate(cfg, machine).feasible
+
+
+@pytest.mark.parametrize("kernel", ["stencil25", "lbm_d3q15", "attention", "wkv", "lbm_d3q27"])
 def test_estimator_pick_compiles(chip, kernel):
     struct, machine = chip
     limit = machine.vmem_usable
@@ -120,6 +149,9 @@ def test_estimator_pick_compiles(chip, kernel):
     elif kernel == "lbm_d3q15":
         pick, _ = lbm_select(LBM, jnp.float32, machine=machine)
         assert _lbm(struct, machine, pick)
+    elif kernel == "lbm_d3q27":
+        pick, _ = lbm27_select(LBM27, jnp.float32, machine=machine)
+        assert _lbm27(struct, machine, pick)
     elif kernel == "attention":
         b, hq, hkv, s, d = ATTN
         (bq, bkv), _ = select_blocks(b, hq, hkv, s, d, jnp.bfloat16, True, machine=machine)
@@ -161,13 +193,17 @@ def _in_scope(fn, scoped: bool):
 
 
 @pytest.mark.parametrize("how", ["entry", "entry_in_scope", "kernel_in_scope"])
-@pytest.mark.parametrize("config", ["stencil25-r4-f32", "lbm-d3q15-f32"])
+@pytest.mark.parametrize("config", ["stencil25-r4-f32", "lbm-d3q15-f32", "lbm-twophase-d3q27-f32"])
 def test_entry_kernel_matches_the_benchmark_kernel_pattern(chip, monkeypatch, config, how):
     """The chip benchmark finds the kernel by ``kernel_pattern``.  The kernel
     keeps its name (``pallas_call(name=...)``) when the entry is called inside
-    an outer scope, and when the kernel itself is, in a jit of another name."""
+    an outer scope, and when the kernel itself is, in a jit of another name.
+    The entry runs one kernel, or two in the two-phase step, whose D3Q15
+    kernel the pattern must leave out and ``phase_kernel_pattern`` find."""
     from repro.kernels.lbm_d3q15 import lbm_step
     from repro.kernels.lbm_d3q15 import ops as lbm_ops
+    from repro.kernels.lbm_d3q27 import ops as lbm27_ops
+    from repro.kernels.lbm_d3q27 import twophase_step
     from repro.kernels.stencil25 import ops as stencil_ops
     from repro.kernels.stencil25 import stencil25
 
@@ -179,14 +215,29 @@ def test_entry_kernel_matches_the_benchmark_kernel_pattern(chip, monkeypatch, co
         fn = (lambda x: stencil25_pallas(x, r=4, block=(8, 8), vmem_limit_bytes=limit)
               ) if how == "kernel_in_scope" else (lambda x: stencil25(x, r=4))
         args = (struct(STENCIL),)
-    else:
+    elif spec["kernel"] == "lbm_d3q15":
         monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
         fn = (lambda f, p, v: lbm_step_pallas(f, p, v, block=(8, 8), vmem_limit_bytes=limit)
               ) if how == "kernel_in_scope" else lbm_step
         nz, ny, nx = LBM
         args = struct((15, nz, ny, nx)), struct(LBM), struct((3, nz, ny, nx))
-    (kernel,) = _kernel_instructions(jax.jit(_in_scope(fn, how != "entry")).lower(*args))
-    assert re.search(spec["kernel_pattern"], kernel), kernel
+    else:
+        monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
+        monkeypatch.setattr(lbm27_ops, "device_machine", lambda: machine)
+        fn = (lambda g, p, v: hydro_step_pallas(g, p, v, block=(8, 8), vmem_limit_bytes=limit)
+              ) if how == "kernel_in_scope" else twophase_step
+        nz, ny, nx = LBM
+        args = (struct((15, nz, ny, nx)), struct((27, nz, ny, nx)), struct(LBM),
+                struct((3, nz, ny, nx)))
+        if how == "kernel_in_scope":
+            args = args[1:]
+    kernels = _kernel_instructions(jax.jit(_in_scope(fn, how != "entry")).lower(*args))
+    two = spec["kernel"] == "lbm_d3q27" and how != "kernel_in_scope"
+    assert len(kernels) == (2 if two else 1), kernels
+    (kernel,) = [k for k in kernels if re.search(spec["kernel_pattern"], k)]
+    if two:  # the D3Q15 kernel, which the cell's phase_kernel_pattern reads
+        (other,) = [k for k in kernels if k != kernel]
+        assert re.search(spec["phase_kernel_pattern"], other), other
 
 
 @pytest.mark.parametrize("name", ["flash_attention", "wkv"])
