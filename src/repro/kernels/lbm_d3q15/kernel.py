@@ -1,11 +1,16 @@
 """Pallas TPU kernel: D3Q15 Allen-Cahn interface-tracking LB step (paper app 2).
 
-TPU adaptation: tiles over (z, y); x is the lane dimension, ghost-padded by 1.
+TPU adaptation: tiles over (z, y); x is the lane dimension and every block
+holds whole x rows, so the periodic x neighbours are lane rotations of the
+row (no ghost-padded copy of f, phase or vel).
 Halo (range-1, including corners, for the pull streaming and the 7pt phase
 stencil) is expressed with 3x3 overlapping neighbor BlockSpecs for the pdf and
-phase arrays; velocity needs the center tile only.  Block shape selection is
-estimator-guided via `ops.select_block` — exactly the paper's configuration-
-selection use-case, with VMEM feasibility as the hard capacity gate.
+phase arrays; velocity needs the center tile only.  :func:`input_blocks` is
+the one description of these blocks; the kernel clamps them to the grid and
+``ops.config_space`` gives them to the estimator as they are.  Block shape
+selection is estimator-guided via `ops.select_block` — exactly the paper's
+configuration-selection use-case, with VMEM feasibility as the hard capacity
+gate.
 """
 from __future__ import annotations
 
@@ -22,7 +27,7 @@ NEIGHBORS = [(dz, dy) for dz in (-1, 0, 1) for dy in (-1, 0, 1)]
 
 
 def _assemble(tiles, bz: int, by: int, halo: int):
-    """3x3 tiles (each (..., bz, by, nxp)) -> (..., bz+2h, by+2h, nxp) window."""
+    """3x3 tiles (each (..., bz, by, nx)) -> (..., bz+2h, by+2h, nx) window."""
     rows = []
     for iz in range(3):
         rows.append(jnp.concatenate([tiles[iz * 3 + iy] for iy in range(3)], axis=-2))
@@ -36,43 +41,36 @@ def _assemble(tiles, bz: int, by: int, halo: int):
 
 
 def _lbm_kernel(*refs, bz: int, by: int, nx: int, tau: float, width: float):
-    """refs: 9 pdf tiles (15,bz,by,nxp), 9 phase tiles (bz,by,nxp), 1 vel tile
-    (3,bz,by,nxp), then outputs: f_out (15,bz,by,nx), phase_out (bz,by,nx)."""
+    """refs: 9 pdf tiles (15,bz,by,nx), 9 phase tiles (bz,by,nx), 1 vel tile
+    (3,bz,by,nx), then outputs: f_out (15,bz,by,nx), phase_out (bz,by,nx)."""
     f_tiles = [refs[i][...] for i in range(9)]
     p_tiles = [refs[9 + i][...] for i in range(9)]
     vel = refs[18][...]
     f_out_ref, phase_out_ref = refs[19], refs[20]
 
-    fwin = _assemble(f_tiles, bz, by, 1)  # (15, bz+2, by+2, nxp)
-    pwin = _assemble(p_tiles, bz, by, 1)  # (bz+2, by+2, nxp)
+    fwin = _assemble(f_tiles, bz, by, 1)  # (15, bz+2, by+2, nx)
+    pwin = _assemble(p_tiles, bz, by, 1)  # (bz+2, by+2, nx)
 
-    def center_x(a):  # crop the ghost-padded x dim of an unassembled tile
-        return a[..., 1 : 1 + nx]
+    def xshift(a, cx: int):
+        """a(x - cx) at x, periodic: a lane rotation of the whole row."""
+        return pltpu.roll(a, cx % nx, a.ndim - 1) if cx else a
 
     # pull streaming: value at p comes from p - c_q
     pulled = []
     for q, (cx, cy, cz) in enumerate(DIRS):
-        pulled.append(
-            fwin[
-                q,
-                1 - cz : 1 - cz + bz,
-                1 - cy : 1 - cy + by,
-                1 - cx : 1 - cx + nx,
-            ]
-        )
+        pulled.append(xshift(fwin[q, 1 - cz : 1 - cz + bz, 1 - cy : 1 - cy + by], cx))
     phi_new = pulled[0]
     for q in range(1, 15):
         phi_new = phi_new + pulled[q]
     # 7pt central differences on the input phase window
-    gx = 0.5 * (pwin[1 : 1 + bz, 1 : 1 + by, 2 : 2 + nx] - pwin[1 : 1 + bz, 1 : 1 + by, 0:nx])
-    gy = 0.5 * (pwin[1 : 1 + bz, 2 : 2 + by, 1 : 1 + nx] - pwin[1 : 1 + bz, 0:by, 1 : 1 + nx])
-    gz = 0.5 * (pwin[2 : 2 + bz, 1 : 1 + by, 1 : 1 + nx] - pwin[0:bz, 1 : 1 + by, 1 : 1 + nx])
+    prow = pwin[1 : 1 + bz, 1 : 1 + by]
+    gx = 0.5 * (xshift(prow, -1) - xshift(prow, 1))
+    gy = 0.5 * (pwin[1 : 1 + bz, 2 : 2 + by] - pwin[1 : 1 + bz, 0:by])
+    gz = 0.5 * (pwin[2 : 2 + bz, 1 : 1 + by] - pwin[0:bz, 1 : 1 + by])
     inv_norm = jax.lax.rsqrt(gx * gx + gy * gy + gz * gz + 1e-12)
     nxv, nyv, nzv = gx * inv_norm, gy * inv_norm, gz * inv_norm
     sharp = (4.0 * phi_new * (1.0 - phi_new)) / width
-    ux = center_x(vel[0])
-    uy = center_x(vel[1])
-    uz = center_x(vel[2])
+    ux, uy, uz = vel[0], vel[1], vel[2]
     inv_tau = 1.0 / tau
     outs = []
     for q, (cx, cy, cz) in enumerate(DIRS):
@@ -83,6 +81,47 @@ def _lbm_kernel(*refs, bz: int, by: int, nx: int, tau: float, width: float):
         outs.append(pulled[q] - inv_tau * (pulled[q] - heq) + forcing)
     f_out_ref[...] = jnp.stack(outs, axis=0)
     phase_out_ref[...] = phi_new
+
+
+def input_blocks(block: tuple[int, int], nx: int):
+    """``(name, operand, block_shape, index_map)`` of the kernel's inputs, in
+    the kernel's order: the nine (dz, dy) neighbour tiles of f (15, nz, ny, nx),
+    the nine of phase (nz, ny, nx), then vel's (3, nz, ny, nx) centre tile.
+    Every block holds whole x rows.  The index maps are the interior ones."""
+    bz, by = block
+    out = [(f"f{k}", "f", (15, bz, by, nx), lambda i, j, dz=dz, dy=dy: (0, i + dz, j + dy, 0))
+           for k, (dz, dy) in enumerate(NEIGHBORS)]
+    out += [(f"p{k}", "phase", (bz, by, nx), lambda i, j, dz=dz, dy=dy: (i + dz, j + dy, 0))
+            for k, (dz, dy) in enumerate(NEIGHBORS)]
+    out.append(("vel", "vel", (3, bz, by, nx), lambda i, j: (0, i, j, 0)))
+    return tuple(out)
+
+
+def output_blocks(block: tuple[int, int], nx: int):
+    """``(name, block_shape, index_map)`` of the outputs f' and phi'."""
+    bz, by = block
+    return (("f_out", (15, bz, by, nx), lambda i, j: (0, i, j, 0)),
+            ("phase_out", (bz, by, nx), lambda i, j: (i, j, 0)))
+
+
+def block_specs(shape: tuple[int, int, int], block: tuple[int, int]):
+    """``(names, in_specs, out_specs)``: the :func:`input_blocks` with each
+    block index clamped to its operand's blocks of that shape, and the
+    output tiles of f' and phi'."""
+    nz, ny, nx = shape
+    extents = {"f": (15, nz, ny, nx), "phase": (nz, ny, nx), "vel": (3, nz, ny, nx)}
+
+    def clamped(index_map, block_shape, full):
+        def clamped_map(i, j):
+            return tuple(jnp.clip(b, 0, n // s - 1)
+                         for b, s, n in zip(index_map(i, j), block_shape, full))
+
+        return clamped_map
+
+    blocks = input_blocks(block, nx)
+    in_specs = [pl.BlockSpec(bs, clamped(fn, bs, extents[op])) for _, op, bs, fn in blocks]
+    out_specs = tuple(pl.BlockSpec(bs, fn) for _, bs, fn in output_blocks(block, nx))
+    return tuple(n for n, *_ in blocks), in_specs, out_specs
 
 
 def lbm_step_pallas(
@@ -100,48 +139,12 @@ def lbm_step_pallas(
     bz, by = block
     if nz % bz or ny % by:
         raise ValueError(f"grid {(nz, ny, nx)} not divisible by block {block}")
-    nzb, nyb = nz // bz, ny // by
-    nxp = nx + 2
-    fp = jnp.pad(f, ((0, 0), (0, 0), (0, 0), (1, 1)), mode="wrap")
-    pp = jnp.pad(phase, ((0, 0), (0, 0), (1, 1)), mode="wrap")
-    vp = jnp.pad(vel, ((0, 0), (0, 0), (0, 0), (1, 1)), mode="wrap")
-
-    def make_map4(dz, dy):  # (component, z, y, x) arrays
-        def index_map(i, j):
-            return (
-                0,
-                jnp.clip(i + dz, 0, nzb - 1),
-                jnp.clip(j + dy, 0, nyb - 1),
-                0,
-            )
-
-        return index_map
-
-    def make_map3(dz, dy):  # (z, y, x) arrays
-        def index_map(i, j):
-            return (
-                jnp.clip(i + dz, 0, nzb - 1),
-                jnp.clip(j + dy, 0, nyb - 1),
-                0,
-            )
-
-        return index_map
-
-    in_specs = [
-        pl.BlockSpec((15, bz, by, nxp), make_map4(dz, dy)) for dz, dy in NEIGHBORS
-    ]
-    in_specs += [
-        pl.BlockSpec((bz, by, nxp), make_map3(dz, dy)) for dz, dy in NEIGHBORS
-    ]
-    in_specs += [pl.BlockSpec((3, bz, by, nxp), make_map4(0, 0))]
-    out_specs = (
-        pl.BlockSpec((15, bz, by, nx), lambda i, j: (0, i, j, 0)),
-        pl.BlockSpec((bz, by, nx), lambda i, j: (i, j, 0)),
-    )
+    _, in_specs, out_specs = block_specs((nz, ny, nx), block)
+    fields = {"f": f, "phase": phase, "vel": vel}
     kernel = functools.partial(_lbm_kernel, bz=bz, by=by, nx=nx, tau=tau, width=width)
     return pl.pallas_call(
         kernel,
-        grid=(nzb, nyb),
+        grid=(nz // bz, ny // by),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=(
@@ -151,4 +154,4 @@ def lbm_step_pallas(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name="lbm_step",
-    )(*([fp] * 9 + [pp] * 9 + [vp]))
+    )(*(fields[op] for _, op, _, _ in input_blocks(block, nx)))

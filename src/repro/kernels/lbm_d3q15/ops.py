@@ -6,53 +6,32 @@ import jax.numpy as jnp
 from ...core import tpu_estimator as te
 from ...core.machine import TPUMachine, device_machine
 from ..entry import entry_point, timed_pick
-from .kernel import lbm_step_pallas
+from .kernel import input_blocks, lbm_step_pallas, output_blocks
 from .ref import init_fields, lbm_step_ref
 
 CANDIDATE_BLOCKS = ((4, 4), (8, 8), (8, 16), (16, 8), (16, 16), (32, 8), (8, 32))
 
 
 def config_space(shape: tuple[int, int, int], dtype_bits: int):
-    """Candidate PallasConfigs for the LBM step (pdf 3x3 + phase 3x3 + vel + outs)."""
+    """Candidate PallasConfigs for `core.tpu_estimator` ranking: the kernel's
+    own inputs (:func:`kernel.input_blocks`: nine neighbour tiles of f and of
+    phase, vel's centre, all over whole x rows) plus the outputs f' and phi',
+    at every block of :data:`CANDIDATE_BLOCKS` that tiles the grid."""
     nz, ny, nx = shape
-    nxp = nx + 2
-    neighbors = [(dz, dy) for dz in (-1, 0, 1) for dy in (-1, 0, 1)]
     out = []
     for bz, by in CANDIDATE_BLOCKS:
         if nz % bz or ny % by:
             continue
-        accesses = []
-        for k, (dz, dy) in enumerate(neighbors):
-            accesses.append(
-                te.BlockAccess(
-                    f"f{k}",
-                    (15, bz, by, nxp),
-                    (lambda dz=dz, dy=dy: (lambda i, j: (0, i + dz, j + dy, 0)))(),
-                    dtype_bits,
-                )
-            )
-        for k, (dz, dy) in enumerate(neighbors):
-            accesses.append(
-                te.BlockAccess(
-                    f"p{k}",
-                    (bz, by, nxp),
-                    (lambda dz=dz, dy=dy: (lambda i, j: (i + dz, j + dy, 0)))(),
-                    dtype_bits,
-                )
-            )
-        accesses.append(
-            te.BlockAccess("vel", (3, bz, by, nxp), lambda i, j: (0, i, j, 0), dtype_bits)
-        )
-        accesses.append(
-            te.BlockAccess(
-                "f_out", (15, bz, by, nx), lambda i, j: (0, i, j, 0), dtype_bits, True
-            )
-        )
-        accesses.append(
-            te.BlockAccess(
-                "phase_out", (bz, by, nx), lambda i, j: (i, j, 0), dtype_bits, True
-            )
-        )
+        accesses = [
+            te.BlockAccess(name=name, block_shape=block_shape, index_map=index_map,
+                           dtype_bits=dtype_bits)
+            for name, _, block_shape, index_map in input_blocks((bz, by), nx)
+        ]
+        accesses += [
+            te.BlockAccess(name=name, block_shape=block_shape, index_map=index_map,
+                           dtype_bits=dtype_bits, is_output=True)
+            for name, block_shape, index_map in output_blocks((bz, by), nx)
+        ]
         out.append(
             te.PallasConfig(
                 name=f"lbm_bz{bz}_by{by}",

@@ -9,8 +9,10 @@ import pytest
 from repro.core import tpu_estimator as te
 from repro.core.machine import TPU_V5E
 from repro.kernels.attention import flash_attention, mha_ref, select_blocks
+from repro.kernels.lbm_d3q15 import config_space as lbm_space
 from repro.kernels.lbm_d3q15 import init_fields, lbm_step, lbm_step_ref
 from repro.kernels.lbm_d3q15 import select_block as lbm_select
+from repro.kernels.lbm_d3q15.kernel import block_specs as lbm_block_specs
 from repro.kernels.lbm_d3q27 import (
     TwoPhaseParams,
     equilibrium,
@@ -158,6 +160,7 @@ def test_vmem_gate_holds_the_blocks_to_vmem_usable(block_mib, feasible):
         ("stencil25", (1024, 1024, 512), (32, 32)),  # stencil25.bulk
         ("stencil25", (32, 32, 512), (32, 32)),  # stencil25.ensemble
         ("lbm_d3q15", (256, 256, 256), (8, 8)),  # lbm_d3q15.bulk
+        ("lbm_d3q15", (32, 32, 256), (8, 8)),  # lbm_d3q15.ensemble
     ],
 )
 def test_estimator_picks_of_the_benchmark_cells_stand(kernel, shape, pick):
@@ -201,6 +204,28 @@ def test_lbm_allclose(shape, dtype, block):
     s = (slice(None), slice(1, -1), slice(1, -1), slice(None))
     np.testing.assert_allclose(fo[s], fr[s], **_tol(dtype))
     np.testing.assert_allclose(po[1:-1, 1:-1], pr[1:-1, 1:-1], **_tol(dtype))
+
+
+@pytest.mark.parametrize(
+    "shape, block", [((256, 256, 256), (8, 8)), ((256, 256, 256), (16, 16)), ((32, 32, 256), (8, 8))]
+)
+def test_lbm_config_space_matches_kernel_block_specs(shape, block):
+    """The estimator's candidate describes the kernel's own BlockSpecs, each
+    over whole x rows (no ghost-padded copy): nine tiles of f, nine of phase,
+    vel's centre, then f' and phi'."""
+    bits = 32
+    cfg = next(c for c in lbm_space(shape, bits) if c.meta["block"] == block)
+    names, in_specs, out_specs = lbm_block_specs(shape, block)
+    assert len(in_specs) == 9 + 9 + 1 == len(names)
+    assert len(cfg.accesses) == len(in_specs) + 2
+    assert [a.is_output for a in cfg.accesses] == [False] * len(in_specs) + [True, True]
+    assert [a.name for a in cfg.accesses[: len(names)]] == list(names)
+    nzb, nyb = shape[0] // block[0], shape[1] // block[1]
+    for acc, spec in zip(cfg.accesses, [*in_specs, *out_specs]):
+        assert tuple(acc.block_shape) == tuple(spec.block_shape), acc.name
+        assert acc.block_shape[-1] == shape[2], acc.name
+        for i, j in ((1, 1), (1, nyb - 2), (nzb - 2, nyb - 2)):  # interior: no clamp applies
+            assert tuple(int(v) for v in spec.index_map(i, j)) == acc.index_map(i, j), acc.name
 
 
 def test_lbm_mass_conservation():

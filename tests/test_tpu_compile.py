@@ -240,6 +240,32 @@ def test_entry_kernel_matches_the_benchmark_kernel_pattern(chip, monkeypatch, co
         assert re.search(spec["phase_kernel_pattern"], other), other
 
 
+@pytest.mark.parametrize("entry", ["lbm_step", "twophase_step"])
+def test_lbm_entries_compile_without_x_pads(chip, monkeypatch, entry):
+    """The D3Q15 kernel reads whole x rows and rotates them in lanes, so at
+    the benchmark's 256^3 its entry compiles to the kernel alone, with no pad
+    op, and the two-phase step, which runs it, holds no pad op either."""
+    from repro.kernels.lbm_d3q15 import lbm_step
+    from repro.kernels.lbm_d3q15 import ops as lbm_ops
+    from repro.kernels.lbm_d3q27 import ops as lbm27_ops
+    from repro.kernels.lbm_d3q27 import twophase_step
+
+    struct, machine = chip
+    monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
+    monkeypatch.setattr(lbm27_ops, "device_machine", lambda: machine)
+    nz, ny, nx = LBM27
+    f, phase, vel = struct((15, nz, ny, nx)), struct(LBM27), struct((3, nz, ny, nx))
+    if entry == "lbm_step":
+        lowered = lbm_step.lower(f, phase, vel)
+        assert re.search(r"module @jit_lbm_step\b", lowered.as_text())
+    else:
+        lowered = jax.jit(twophase_step).lower(f, struct((27, nz, ny, nx)), phase, vel)
+    ops = [line for line in lowered.compile().as_text().splitlines()
+           if re.search(r"\b(pad|custom-call)\(", line)]
+    assert not [op for op in ops if re.search(r"\bpad\(", op)], ops
+    assert len(ops) == (1 if entry == "lbm_step" else 2), ops
+
+
 @pytest.mark.parametrize("name", ["flash_attention", "wkv"])
 def test_model_kernel_instruction_is_named_by_its_pallas_call(chip, name):
     """Called inside a scope, in a jit of another name, the kernel still
