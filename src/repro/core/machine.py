@@ -145,6 +145,7 @@ class TPUMachine:
     sublanes: int = 8  # native (8, 128) fp32 vector tile
     lanes: int = 128
     vpu_flops: float = 4e12  # elementwise VPU throughput, FLOP/s
+    grid_step_s: float = 2e-7  # per-step sequencer floor (mostly hidden by pipelining)
 
     def peak_flops(self, dtype_bits: int) -> float:
         return self.peak_bf16 if dtype_bits <= 16 else self.peak_fp32

@@ -134,9 +134,6 @@ class TPUEstimate:
         return max(terms, key=terms.get)
 
 
-GRID_STEP_OVERHEAD_S = 2e-7  # per-step sequencer floor (mostly hidden by pipelining)
-
-
 def _breaks_tiling(tile, coeffs, offset, grid, machine: TPUMachine) -> bool:
     """Mosaic refuses a block whose last two dims are not multiples of
     (sublanes, lanes) unless the block spans the whole array.  The IR holds no
@@ -227,7 +224,7 @@ def estimate_ir(ir: AccessIR, machine: TPUMachine = TPU_V5E) -> TPUEstimate:
         # MXU utilization: matmul dims padded to 128 (the lane/bank analogue)
         peak *= _mxu_utilization(ir, machine)
     est.t_compute = ir.flops_per_iter * steps / max(peak, 1.0)
-    est.t_grid = steps * GRID_STEP_OVERHEAD_S
+    est.t_grid = steps * machine.grid_step_s
     return est
 
 

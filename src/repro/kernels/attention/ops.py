@@ -7,11 +7,13 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPUMachine, device_machine
+from ...core.machine import TPUMachine
 
 # GPU-space entry: the AccessIR builder that pushes this kernel through the
 # paper §III analytic pipeline (registry kernel "attention", backend "gpu").
 from ...frontend.builders import attention_gpu_ir
+from ..entry import chip
+from ..tiling import pick
 from .kernel import flash_attention_pallas
 from .ref import mha_ref
 
@@ -105,13 +107,9 @@ def select_blocks(
     *,
     machine: TPUMachine,
 ) -> tuple[tuple[int, int], te.TPUEstimate]:
-    bits = jnp.dtype(dtype).itemsize * 8
-    cands = config_space(b, hq, hkv, s, d, bits, causal)
-    if not cands:
-        raise ValueError(
-            f"no candidate blocks {CANDIDATE_BLOCKS} divide sequence length {s}"
-        )
-    cfg, est = te.select_config(cands, machine)
+    cands = config_space(b, hq, hkv, s, d, jnp.dtype(dtype).itemsize * 8, causal)
+    cfg, est = pick(cands, machine,
+                    f"no candidate blocks {CANDIDATE_BLOCKS} divide sequence length {s}")
     return (cfg.meta["block_q"], cfg.meta["block_kv"]), est
 
 
@@ -131,7 +129,7 @@ def flash_attention(
     :func:`stencil25.ops.stencil25` (either block left ``None`` is picked)."""
     b, hq, s, d = q.shape
     hkv = k.shape[1]
-    machine = None if interpret else device_machine()
+    machine, vmem_limit = chip(interpret)
     if block_q is None or block_kv is None:
         (bq, bkv), _ = select_blocks(
             b, hq, hkv, s, d, q.dtype, causal, machine=machine
@@ -140,8 +138,7 @@ def flash_attention(
         block_kv = block_kv or bkv
     return flash_attention_pallas(
         q, k, v, causal=causal, block_q=block_q, block_kv=block_kv,
-        interpret=interpret,
-        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
+        interpret=interpret, vmem_limit_bytes=vmem_limit,
     )
 
 

@@ -1,5 +1,6 @@
 """What every estimator-picked kernel entry point shares: a jitted body under
-the entry's own name, a host span around each call, and a timed pick.
+the entry's own name, a host span around each call, a timed pick, and the
+chip it picks and compiles for.
 
 Spans (``repro.obs.trace``) land in a running ``jax.profiler`` session, so a
 device trace shows each call's host dispatch as ``<entry>.call`` and, where
@@ -13,6 +14,7 @@ import functools
 
 import jax
 
+from ..core.machine import TPUMachine, device_machine
 from ..obs import metrics, trace
 
 
@@ -52,3 +54,13 @@ def timed_pick(entry: str, select, *args, **kwargs):
         out = select(*args, **kwargs)
     metrics.histogram("estimator.pick_seconds", entry=entry).observe(sp.duration_s)
     return out
+
+
+def chip(interpret: bool) -> tuple[TPUMachine | None, int | None]:
+    """``(machine, vmem_limit_bytes)`` an entry picks for and compiles under:
+    the chip JAX runs on and its ``vmem_usable``, or ``(None, None)`` in
+    interpret mode, which runs on no chip (so its caller names the block)."""
+    if interpret:
+        return None, None
+    machine = device_machine()
+    return machine, machine.vmem_usable

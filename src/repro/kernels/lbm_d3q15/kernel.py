@@ -5,12 +5,12 @@ holds whole x rows, so the periodic x neighbours are lane rotations of the
 row (no ghost-padded copy of f, phase or vel).
 Halo (range-1, including corners, for the pull streaming and the 7pt phase
 stencil) is expressed with 3x3 overlapping neighbor BlockSpecs for the pdf and
-phase arrays; velocity needs the center tile only.  :func:`input_blocks` is
-the one description of these blocks; the kernel clamps them to the grid and
-``ops.config_space`` gives them to the estimator as they are.  Block shape
-selection is estimator-guided via `ops.select_block` — exactly the paper's
-configuration-selection use-case, with VMEM feasibility as the hard capacity
-gate.
+phase arrays; velocity needs the center tile only.  :func:`input_blocks` and
+:func:`output_blocks` are the one description of these blocks, which
+:mod:`repro.kernels.tiling` turns into the BlockSpecs and the estimator's
+candidates.  Block shape selection is estimator-guided via
+`ops.select_block` — exactly the paper's configuration-selection use-case,
+with VMEM feasibility as the hard capacity gate.
 """
 from __future__ import annotations
 
@@ -21,9 +21,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..tiling import Block, pallas_specs
 from .ref import DIRS, WEIGHTS
 
 NEIGHBORS = [(dz, dy) for dz in (-1, 0, 1) for dy in (-1, 0, 1)]
+# the (bz, by) blocks the estimator ranks, for this kernel and the D3Q27 one
+CANDIDATE_BLOCKS = ((4, 4), (8, 8), (8, 16), (16, 8), (16, 16), (32, 8), (8, 32))
 
 
 def _assemble(tiles, bz: int, by: int, halo: int):
@@ -84,44 +87,24 @@ def _lbm_kernel(*refs, bz: int, by: int, nx: int, tau: float, width: float):
 
 
 def input_blocks(block: tuple[int, int], nx: int):
-    """``(name, operand, block_shape, index_map)`` of the kernel's inputs, in
-    the kernel's order: the nine (dz, dy) neighbour tiles of f (15, nz, ny, nx),
+    """The kernel's inputs (:class:`~repro.kernels.tiling.Block`), in the
+    kernel's order: the nine (dz, dy) neighbour tiles of f (15, nz, ny, nx),
     the nine of phase (nz, ny, nx), then vel's (3, nz, ny, nx) centre tile.
     Every block holds whole x rows.  The index maps are the interior ones."""
     bz, by = block
-    out = [(f"f{k}", "f", (15, bz, by, nx), lambda i, j, dz=dz, dy=dy: (0, i + dz, j + dy, 0))
+    out = [Block(f"f{k}", (15, bz, by, nx), lambda i, j, dz=dz, dy=dy: (0, i + dz, j + dy, 0), "f")
            for k, (dz, dy) in enumerate(NEIGHBORS)]
-    out += [(f"p{k}", "phase", (bz, by, nx), lambda i, j, dz=dz, dy=dy: (i + dz, j + dy, 0))
+    out += [Block(f"p{k}", (bz, by, nx), lambda i, j, dz=dz, dy=dy: (i + dz, j + dy, 0), "phase")
             for k, (dz, dy) in enumerate(NEIGHBORS)]
-    out.append(("vel", "vel", (3, bz, by, nx), lambda i, j: (0, i, j, 0)))
+    out.append(Block("vel", (3, bz, by, nx), lambda i, j: (0, i, j, 0), "vel"))
     return tuple(out)
 
 
 def output_blocks(block: tuple[int, int], nx: int):
-    """``(name, block_shape, index_map)`` of the outputs f' and phi'."""
+    """The output tiles of f' and phi'."""
     bz, by = block
-    return (("f_out", (15, bz, by, nx), lambda i, j: (0, i, j, 0)),
-            ("phase_out", (bz, by, nx), lambda i, j: (i, j, 0)))
-
-
-def block_specs(shape: tuple[int, int, int], block: tuple[int, int]):
-    """``(names, in_specs, out_specs)``: the :func:`input_blocks` with each
-    block index clamped to its operand's blocks of that shape, and the
-    output tiles of f' and phi'."""
-    nz, ny, nx = shape
-    extents = {"f": (15, nz, ny, nx), "phase": (nz, ny, nx), "vel": (3, nz, ny, nx)}
-
-    def clamped(index_map, block_shape, full):
-        def clamped_map(i, j):
-            return tuple(jnp.clip(b, 0, n // s - 1)
-                         for b, s, n in zip(index_map(i, j), block_shape, full))
-
-        return clamped_map
-
-    blocks = input_blocks(block, nx)
-    in_specs = [pl.BlockSpec(bs, clamped(fn, bs, extents[op])) for _, op, bs, fn in blocks]
-    out_specs = tuple(pl.BlockSpec(bs, fn) for _, bs, fn in output_blocks(block, nx))
-    return tuple(n for n, *_ in blocks), in_specs, out_specs
+    return (Block("f_out", (15, bz, by, nx), lambda i, j: (0, i, j, 0), is_output=True),
+            Block("phase_out", (bz, by, nx), lambda i, j: (i, j, 0), is_output=True))
 
 
 def lbm_step_pallas(
@@ -139,8 +122,10 @@ def lbm_step_pallas(
     bz, by = block
     if nz % bz or ny % by:
         raise ValueError(f"grid {(nz, ny, nx)} not divisible by block {block}")
-    _, in_specs, out_specs = block_specs((nz, ny, nx), block)
     fields = {"f": f, "phase": phase, "vel": vel}
+    inputs = input_blocks(block, nx)
+    in_specs, out_specs = pallas_specs((*inputs, *output_blocks(block, nx)),
+                                       {op: a.shape for op, a in fields.items()})
     kernel = functools.partial(_lbm_kernel, bz=bz, by=by, nx=nx, tau=tau, width=width)
     return pl.pallas_call(
         kernel,
@@ -154,4 +139,4 @@ def lbm_step_pallas(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name="lbm_step",
-    )(*(fields[op] for _, op, _, _ in input_blocks(block, nx)))
+    )(*(fields[b.operand] for b in inputs))

@@ -14,9 +14,9 @@ feeds the 27-point gradient and Laplacian, so it reads its centre, four
 strips and four corner pieces; the carried velocity only its centre tile.
 Strip heights come from :func:`repro.kernels.stencil25.kernel.strip_heights`
 at range 1: one z plane, and y rows on the dtype's sublane tile.
-:func:`input_blocks` is the one description of these blocks; the kernel
-clamps them to the grid and ``ops.config_space`` gives them to the estimator
-as they are.
+:func:`input_blocks` and :func:`output_blocks` are the one description of
+these blocks, which :mod:`repro.kernels.tiling` turns into the BlockSpecs and
+the estimator's candidates.
 """
 from __future__ import annotations
 
@@ -28,6 +28,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..stencil25.kernel import strip_heights
+from ..tiling import Block, pallas_specs
 from .ref import DIRS, STEPS, WEIGHTS, TwoPhaseParams
 
 CLASSES = tuple((cz, cy) for cz in STEPS for cy in STEPS)  # class k = components 3k..3k+2
@@ -42,8 +43,8 @@ def _strip_index(side: str, i, k: int):
 
 
 def input_blocks(block: tuple[int, int], nx: int, dtype_bits: int):
-    """``(name, operand, block_shape, index_map)`` of the kernel's inputs, in
-    the kernel's order: for each class of g its centre, z strip, y strip and
+    """The kernel's inputs (:class:`~repro.kernels.tiling.Block`), in the
+    kernel's order: for each class of g its centre, z strip, y strip and
     corner piece (those it streams from), then phase's centre, four strips
     and four corners, then vel's centre.  ``operand`` is ``g`` (27, nz, ny, nx),
     ``phase`` (nz, ny, nx) or ``vel`` (3, nz, ny, nx).  The index maps are
@@ -71,20 +72,20 @@ def input_blocks(block: tuple[int, int], nx: int, dtype_bits: int):
                                      ("_zy", zs, ys)):
             if ("z" in suffix and zs is None) or ("y" in suffix and ys is None):
                 continue  # a side this class does not stream from
-            out.append((f"g{k}{suffix}", "g", *piece(((3, k),), zside, yside)))
+            out.append(Block(f"g{k}{suffix}", *piece(((3, k),), zside, yside), "g"))
     for zside in (None, *SIDES):
         for yside in (None, *SIDES):
             name = "phase" + (f"_z{zside}" if zside else "") + (f"_y{yside}" if yside else "")
-            out.append((name, "phase", *piece((), zside, yside)))
-    out.append(("vel", "vel", (3, bz, by, nx), lambda i, j: (0, i, j, 0)))
+            out.append(Block(name, *piece((), zside, yside), "phase"))
+    out.append(Block("vel", (3, bz, by, nx), lambda i, j: (0, i, j, 0), "vel"))
     return tuple(out)
 
 
 def output_blocks(block: tuple[int, int], nx: int):
-    """``(name, block_shape, index_map)`` of the outputs g' and u'."""
+    """The output tiles of g' and u'."""
     bz, by = block
-    return (("g_out", (27, bz, by, nx), lambda i, j: (0, i, j, 0)),
-            ("vel_out", (3, bz, by, nx), lambda i, j: (0, i, j, 0)))
+    return (Block("g_out", (27, bz, by, nx), lambda i, j: (0, i, j, 0), is_output=True),
+            Block("vel_out", (3, bz, by, nx), lambda i, j: (0, i, j, 0), is_output=True))
 
 
 def _plane(centre, strip, z, dz: int):
@@ -225,26 +226,6 @@ def _hydro_kernel(*refs, names, hy: int, nx: int, p: TwoPhaseParams):
     jax.lax.fori_loop(0, bz, plane_step, 0)
 
 
-def block_specs(shape: tuple[int, int, int], block: tuple[int, int], dtype_bits: int):
-    """``(names, in_specs, out_specs)``: the :func:`input_blocks` with each
-    block index clamped to its operand's blocks of that shape, and the
-    output tiles of g' and u'."""
-    nz, ny, nx = shape
-    extents = {"g": (27, nz, ny, nx), "phase": (nz, ny, nx), "vel": (3, nz, ny, nx)}
-
-    def clamped(index_map, block_shape, full):
-        def clamped_map(i, j):
-            return tuple(jnp.clip(b, 0, n // s - 1)
-                         for b, s, n in zip(index_map(i, j), block_shape, full))
-
-        return clamped_map
-
-    blocks = input_blocks(block, nx, dtype_bits)
-    in_specs = [pl.BlockSpec(bs, clamped(fn, bs, extents[op])) for _, op, bs, fn in blocks]
-    out_specs = tuple(pl.BlockSpec(bs, fn) for _, bs, fn in output_blocks(block, nx))
-    return tuple(n for n, *_ in blocks), in_specs, out_specs
-
-
 def hydro_step_pallas(
     g: jnp.ndarray,
     phase: jnp.ndarray,
@@ -261,11 +242,12 @@ def hydro_step_pallas(
     if nz % bz or ny % by:
         raise ValueError(f"grid {(nz, ny, nx)} not divisible by block {block}")
     bits = g.dtype.itemsize * 8
-    names, in_specs, out_specs = block_specs((nz, ny, nx), block, bits)
     fields = {"g": g, "phase": phase, "vel": vel}
-    operands = [fields[op] for _, op, _, _ in input_blocks(block, nx, bits)]
-    kernel = functools.partial(_hydro_kernel, names=names, hy=strip_heights(1, block, bits)[1],
-                               nx=nx, p=params)
+    inputs = input_blocks(block, nx, bits)
+    in_specs, out_specs = pallas_specs((*inputs, *output_blocks(block, nx)),
+                                       {op: a.shape for op, a in fields.items()})
+    kernel = functools.partial(_hydro_kernel, names=tuple(b.name for b in inputs),
+                               hy=strip_heights(1, block, bits)[1], nx=nx, p=params)
     return pl.pallas_call(
         kernel,
         grid=(nz // bz, ny // by),
@@ -276,4 +258,4 @@ def hydro_step_pallas(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
         name="lbm_d3q27",
-    )(*operands)
+    )(*(fields[b.operand] for b in inputs))

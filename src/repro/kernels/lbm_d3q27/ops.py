@@ -5,46 +5,13 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPUMachine, device_machine
-from ..entry import entry_point, timed_pick
-from ..lbm_d3q15.ops import CANDIDATE_BLOCKS
-from ..lbm_d3q15.ops import lbm_step as _lbm_step
+from ...core.machine import TPUMachine
+from .. import tiling
+from ..entry import chip, entry_point, timed_pick
+from ..lbm_d3q15.kernel import CANDIDATE_BLOCKS
+from ..lbm_d3q15.ops import traced_lbm_step
 from .kernel import hydro_step_pallas, input_blocks, output_blocks
 from .ref import TwoPhaseParams, equilibrium, hydro_step_ref, twophase_step_ref
-
-
-def config_space(shape: tuple[int, int, int], dtype_bits: int):
-    """Candidate PallasConfigs for `core.tpu_estimator` ranking: the kernel's
-    own inputs (:func:`kernel.input_blocks`: each class's centre and the
-    strips and corner pieces it streams from, phase's centre, strips and
-    corners, vel's centre) plus the outputs g' and u', at every block of
-    :data:`CANDIDATE_BLOCKS` that tiles the grid."""
-    nz, ny, nx = shape
-    out = []
-    for bz, by in CANDIDATE_BLOCKS:
-        if nz % bz or ny % by:
-            continue
-        accesses = [
-            te.BlockAccess(name=name, block_shape=block_shape, index_map=index_map,
-                           dtype_bits=dtype_bits)
-            for name, _, block_shape, index_map in input_blocks((bz, by), nx, dtype_bits)
-        ]
-        accesses += [
-            te.BlockAccess(name=name, block_shape=block_shape, index_map=index_map,
-                           dtype_bits=dtype_bits, is_output=True)
-            for name, block_shape, index_map in output_blocks((bz, by), nx)
-        ]
-        out.append(
-            te.PallasConfig(
-                name=f"lbm27_bz{bz}_by{by}",
-                grid=(nz // bz, ny // by),
-                accesses=tuple(accesses),
-                flops_per_step=float(FLOPS_PER_CELL * bz * by * nx),
-                is_matmul=False,
-                meta={"block": (bz, by)},
-            )
-        )
-    return out
 
 
 # the kernel's arithmetic per cell, counted from its body: phase derivatives
@@ -55,14 +22,24 @@ def config_space(shape: tuple[int, int, int], dtype_bits: int):
 FLOPS_PER_CELL = 35 + 14 + 26 + 218 + 27 + 117 + 15 + 16 + 58 + 5 + 3 + 213 + 28 + 211
 
 
+def config_space(shape: tuple[int, int, int], dtype_bits: int):
+    """Candidate PallasConfigs for `core.tpu_estimator` ranking: the kernel's
+    own inputs (:func:`kernel.input_blocks`: each class's centre and the
+    strips and corner pieces it streams from, phase's centre, strips and
+    corners, vel's centre) plus the outputs g' and u', at every block of
+    :data:`repro.kernels.lbm_d3q15.kernel.CANDIDATE_BLOCKS` that tiles the grid."""
+    nx = shape[2]
+    return tiling.block_space(
+        "lbm27", shape, CANDIDATE_BLOCKS,
+        lambda b: (*input_blocks(b, nx, dtype_bits), *output_blocks(b, nx)),
+        dtype_bits, FLOPS_PER_CELL)
+
+
 def select_block(
     shape: tuple[int, int, int], dtype=jnp.float32, *, machine: TPUMachine
 ) -> tuple[tuple[int, int], te.TPUEstimate]:
-    bits = jnp.dtype(dtype).itemsize * 8
-    cands = config_space(shape, bits)
-    if not cands:
-        raise ValueError(f"no candidate block tiles divide grid {shape}")
-    cfg, est = te.select_config(cands, machine)
+    cands = config_space(shape, jnp.dtype(dtype).itemsize * 8)
+    cfg, est = tiling.pick(cands, machine, f"no candidate block tiles divide grid {shape}")
     return cfg.meta["block"], est
 
 
@@ -90,17 +67,15 @@ def twophase_step(
     """
     if interpret and (block is None or phase_block is None):
         raise ValueError("interpret mode runs on no chip: pass block and phase_block")
-    machine = None if interpret else device_machine()
-    # the D3Q15 entry's own body, traced into this jit with its pick span
-    f_new, phase_new = _lbm_step.__wrapped__(
+    machine, vmem_limit = chip(interpret)
+    # the D3Q15 step, traced into this jit with its pick span
+    f_new, phase_new = traced_lbm_step(
         f, phase, vel, tau=params.tau_phase, width=params.sharpening_width,
         block=phase_block, interpret=interpret)
     if block is None:
         block, _ = timed_pick("lbm_d3q27", select_block, g.shape[1:], g.dtype, machine=machine)
-    g_new, vel_new = hydro_step_pallas(
-        g, phase_new, vel, params=params, block=block, interpret=interpret,
-        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
-    )
+    g_new, vel_new = hydro_step_pallas(g, phase_new, vel, params=params, block=block,
+                                       interpret=interpret, vmem_limit_bytes=vmem_limit)
     return f_new, g_new, phase_new, vel_new
 
 

@@ -6,9 +6,10 @@ dimension) stays whole per tile and is ghost-padded by r.  The star reads no
 (z, y) corner, so the z/y halo is five input BlockSpecs over the padded array
 (:func:`input_blocks`): the centre tile, a thin strip on each z side and a
 thin strip on each y side.  The strips' overlap with the neighbour tiles is
-the V_red the paper's estimator models; ``ops.config_space`` describes the
-same five blocks, and ``ops.select_block()`` picks (bz, by) by ranking
-candidates with `core.tpu_estimator` instead of autotuning.
+the V_red the paper's estimator models.  :mod:`repro.kernels.tiling` turns
+these blocks and :func:`output_blocks` into the kernel's BlockSpecs and the
+estimator's candidates, and ``ops.select_block()`` picks (bz, by) by ranking
+them with `core.tpu_estimator` instead of autotuning.
 """
 from __future__ import annotations
 
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..tiling import Block, pallas_specs
 from .ref import star_offsets, star_weights_np
 
 INPUTS = ("centre", "z_lo", "z_hi", "y_lo", "y_hi")
@@ -44,12 +46,11 @@ def strip_heights(r: int, block: tuple[int, int], dtype_bits: int) -> tuple[int,
 
 
 def input_blocks(r: int, block: tuple[int, int], nx: int, dtype_bits: int):
-    """``(name, block_shape, index_map)`` of the kernel's five inputs over the
-    x-padded (nz, ny, nx + 2r) array, in :data:`INPUTS` order.
+    """The kernel's five inputs (:class:`~repro.kernels.tiling.Block`) over the
+    x-padded (nz, ny, nx + 2r) array ``padded``, in :data:`INPUTS` order.
 
     The index maps are the interior ones: the strips sit in the blocks of
-    their own height just outside the centre tile.  The kernel clamps them to
-    the grid; ``ops.config_space`` gives them to the estimator as they are.
+    their own height just outside the centre tile.
     """
     bz, by = block
     hz, hy = strip_heights(r, block, dtype_bits)
@@ -62,7 +63,12 @@ def input_blocks(r: int, block: tuple[int, int], nx: int, dtype_bits: int):
         ((bz, hy, nxp), lambda i, j: (i, j * ky - 1, 0)),
         ((bz, hy, nxp), lambda i, j: (i, (j + 1) * ky, 0)),
     )
-    return tuple((name, shape, fn) for name, (shape, fn) in zip(INPUTS, maps))
+    return tuple(Block(name, shape, fn, "padded") for name, (shape, fn) in zip(INPUTS, maps))
+
+
+def output_blocks(block: tuple[int, int], nx: int):
+    """The (bz, by, nx) output tile."""
+    return (Block("out", (*block, nx), lambda i, j: (i, j, 0), is_output=True),)
 
 
 def _stencil_kernel(c_ref, zlo_ref, zhi_ref, ylo_ref, yhi_ref, out_ref, *, r, nx, weights):
@@ -92,29 +98,6 @@ def _stencil_kernel(c_ref, zlo_ref, zhi_ref, ylo_ref, yhi_ref, out_ref, *, r, nx
     out_ref[...] = acc
 
 
-def block_specs(shape: tuple[int, int, int], r: int, block: tuple[int, int], dtype_bits: int):
-    """``(in_specs, out_spec)`` of the kernel on an (nz, ny, nx) field: the five
-    :func:`input_blocks` over the x-padded array, each block index clamped to
-    the array's blocks of that shape, and the (bz, by, nx) output tile."""
-    nz, ny, nx = shape
-    padded_shape = (nz, ny, nx + 2 * r)
-
-    def clamped(index_map, block_shape):
-        def clamped_map(i, j):
-            return tuple(
-                jnp.clip(b, 0, n // s - 1)
-                for b, s, n in zip(index_map(i, j), block_shape, padded_shape)
-            )
-
-        return clamped_map
-
-    in_specs = [
-        pl.BlockSpec(block_shape, clamped(index_map, block_shape))
-        for _, block_shape, index_map in input_blocks(r, block, nx, dtype_bits)
-    ]
-    return in_specs, pl.BlockSpec((*block, nx), lambda i, j: (i, j, 0))
-
-
 def stencil25_pallas(
     src: jnp.ndarray,
     r: int = 4,
@@ -136,7 +119,8 @@ def stencil25_pallas(
     padded = jnp.pad(src, ((0, 0), (0, 0), (r, r)), mode="edge")
     # weights as python floats: compile-time constants inside the kernel body
     w = tuple(float(v) for v in star_weights_np(r))
-    in_specs, out_spec = block_specs(src.shape, r, block, src.dtype.itemsize * 8)
+    blocks = (*input_blocks(r, block, nx, src.dtype.itemsize * 8), *output_blocks(block, nx))
+    in_specs, (out_spec,) = pallas_specs(blocks, {"padded": padded.shape})
     kernel = functools.partial(_stencil_kernel, r=r, nx=nx, weights=w)
     return pl.pallas_call(
         kernel,

@@ -7,11 +7,13 @@ import jax
 import jax.numpy as jnp
 
 from ...core import tpu_estimator as te
-from ...core.machine import TPUMachine, device_machine
+from ...core.machine import TPUMachine
 
 # GPU-space entry: the AccessIR builder that pushes this kernel through the
 # paper §III analytic pipeline (registry kernel "wkv", backend "gpu").
 from ...frontend.builders import wkv_gpu_ir
+from ..entry import chip
+from ..tiling import pick
 from .kernel import wkv_pallas
 from .ref import wkv_ref
 
@@ -50,12 +52,8 @@ def config_space(BH: int, S: int, K: int, dtype_bits: int = 32):
 def select_chunk(
     BH: int, S: int, K: int, *, machine: TPUMachine
 ) -> tuple[int, te.TPUEstimate]:
-    cands = config_space(BH, S, K)
-    if not cands:
-        raise ValueError(
-            f"no candidate chunk {CANDIDATE_CHUNKS} divides sequence length {S}"
-        )
-    cfg, est = te.select_config(cands, machine)
+    cfg, est = pick(config_space(BH, S, K), machine,
+                    f"no candidate chunk {CANDIDATE_CHUNKS} divides sequence length {S}")
     return cfg.meta["chunk"], est
 
 
@@ -63,13 +61,11 @@ def select_chunk(
 def wkv(r, k, v, wlog, u, chunk: int | None = None, interpret: bool = False):
     """Chunked WKV; chunk and VMEM limit as in :func:`stencil25.ops.stencil25`."""
     BH, S, K = r.shape
-    machine = None if interpret else device_machine()
+    machine, vmem_limit = chip(interpret)
     if chunk is None:
         chunk, _ = select_chunk(BH, S, K, machine=machine)
-    return wkv_pallas(
-        r, k, v, wlog, u, chunk=chunk, interpret=interpret,
-        vmem_limit_bytes=None if machine is None else machine.vmem_usable,
-    )
+    return wkv_pallas(r, k, v, wlog, u, chunk=chunk, interpret=interpret,
+                      vmem_limit_bytes=vmem_limit)
 
 
 __all__ = ["wkv", "wkv_ref", "select_chunk", "config_space", "wkv_gpu_ir"]

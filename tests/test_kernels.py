@@ -1,6 +1,8 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracles."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,6 @@ from repro.kernels.attention import flash_attention, mha_ref, select_blocks
 from repro.kernels.lbm_d3q15 import config_space as lbm_space
 from repro.kernels.lbm_d3q15 import init_fields, lbm_step, lbm_step_ref
 from repro.kernels.lbm_d3q15 import select_block as lbm_select
-from repro.kernels.lbm_d3q15.kernel import block_specs as lbm_block_specs
 from repro.kernels.lbm_d3q27 import (
     TwoPhaseParams,
     equilibrium,
@@ -21,12 +22,13 @@ from repro.kernels.lbm_d3q27 import (
     twophase_step_ref,
 )
 from repro.kernels.lbm_d3q27 import config_space as lbm27_space
-from repro.kernels.lbm_d3q27.kernel import block_specs as lbm27_block_specs
+from repro.kernels.lbm_d3q27 import select_block as lbm27_select
 from repro.kernels.lbm_d3q27.kernel import hydro_step_pallas
 from repro.kernels.lbm_d3q27.ref import DIRS as DIRS27
 from repro.kernels.stencil25 import config_space, select_block, stencil25, stencil25_ref
 from repro.kernels.stencil25.kernel import INPUTS as STENCIL_INPUTS
-from repro.kernels.stencil25.kernel import block_specs, strip_heights
+from repro.kernels.stencil25.kernel import strip_heights
+from repro.kernels.tiling import Block, pallas_specs
 
 RNG = np.random.default_rng(42)
 
@@ -102,19 +104,85 @@ def test_stencil_strip_heights(r, block, dtype_bits, heights):
     assert strip_heights(r, block, dtype_bits) == heights
 
 
-def test_stencil_config_space_matches_kernel_block_specs():
-    """The estimator's candidate describes the kernel's own BlockSpecs: the
-    centre, two z strips, two y strips and ``out``, with no (z, y) corner."""
-    shape, r, bits = (1024, 1024, 512), 4, 32
-    cfg = next(c for c in config_space(shape, r, bits) if c.meta["block"] == (32, 32))
-    in_specs, out_spec = block_specs(shape, r, (32, 32), bits)
-    assert len(in_specs) == 5 and len(cfg.accesses) == 6
-    assert [a.is_output for a in cfg.accesses] == [False] * 5 + [True]
-    assert [a.name for a in cfg.accesses[:5]] == list(STENCIL_INPUTS)
-    for acc, spec in zip(cfg.accesses, [*in_specs, out_spec]):
+# (kernel, shape, block), with each kernel's count of inputs: stencil25's
+# centre and four strips; D3Q15's nine tiles of f, nine of phase and vel's
+# centre; D3Q27's nine class centres, six z strips, six y strips, four corner
+# pieces, phase's centre, four strips and four corners, and vel's centre
+BLOCK_DECLARATIONS = {"stencil25": 5, "lbm_d3q15": 9 + 9 + 1,
+                      "lbm_d3q27": 9 + 6 + 6 + 4 + 1 + 4 + 4 + 1}
+
+
+@pytest.mark.parametrize(
+    "kernel, shape, block",
+    [
+        ("stencil25", (1024, 1024, 512), (32, 32)),
+        ("lbm_d3q15", (256, 256, 256), (8, 8)),
+        ("lbm_d3q15", (256, 256, 256), (16, 16)),
+        ("lbm_d3q15", (32, 32, 256), (8, 8)),
+        ("lbm_d3q27", (256, 256, 256), (16, 16)),
+    ],
+)
+def test_config_space_matches_kernel_block_specs(monkeypatch, kernel, shape, block):
+    """The estimator's candidate describes the BlockSpecs the kernel itself
+    builds: the same blocks by name, shape and interior index map, inputs
+    then outputs, with no (z, y) corner in stencil25's and every LBM block
+    over whole x rows (no ghost-padded copy)."""
+    import repro.kernels.lbm_d3q15.kernel as lbm_kernel
+    import repro.kernels.lbm_d3q27.kernel as lbm27_kernel
+    import repro.kernels.stencil25.kernel as stencil_kernel
+
+    nz, ny, nx = shape
+    bits, r = 32, 4
+    field = lambda *lead: jax.ShapeDtypeStruct((*lead, *shape), jnp.float32)  # noqa: E731
+    if kernel == "stencil25":
+        module, space = stencil_kernel, config_space(shape, r, bits)
+        run, args = functools.partial(stencil_kernel.stencil25_pallas, r=r), (field(),)
+    elif kernel == "lbm_d3q15":
+        module, space = lbm_kernel, lbm_space(shape, bits)
+        run, args = lbm_kernel.lbm_step_pallas, (field(15), field(), field(3))
+    else:
+        module, space = lbm27_kernel, lbm27_space(shape, bits)
+        run, args = lbm27_kernel.hydro_step_pallas, (field(27), field(), field(3))
+    built = []
+    monkeypatch.setattr(module, "pallas_specs",
+                        lambda blocks, extents: built.append((blocks, extents))
+                        or pallas_specs(blocks, extents))
+    jax.eval_shape(functools.partial(run, block=block), *args)
+    ((blocks, extents),) = built
+    in_specs, out_specs = pallas_specs(blocks, extents)
+    n_inputs = BLOCK_DECLARATIONS[kernel]
+    assert len(in_specs) == n_inputs
+    cfg = next(c for c in space if c.meta["block"] == block)
+    assert len(cfg.accesses) == n_inputs + len(out_specs) == len(blocks)
+    assert [a.is_output for a in cfg.accesses] == [False] * n_inputs + [True] * len(out_specs)
+    assert [a.name for a in cfg.accesses] == [b.name for b in blocks]
+    if kernel == "stencil25":
+        assert [a.name for a in cfg.accesses[:5]] == list(STENCIL_INPUTS)
+        assert extents == {"padded": (nz, ny, nx + 2 * r)}
+    nzb, nyb = nz // block[0], ny // block[1]
+    for acc, spec in zip(cfg.accesses, [*in_specs, *out_specs]):
         assert tuple(acc.block_shape) == tuple(spec.block_shape), acc.name
-        for i, j in ((1, 1), (5, 17), (30, 30)):  # interior: no clamp applies
+        if kernel != "stencil25":
+            assert acc.block_shape[-1] == nx, acc.name
+        for i, j in ((1, 1), (1, nyb - 2), (nzb - 2, nyb - 2)):  # interior: no clamp applies
             assert tuple(int(v) for v in spec.index_map(i, j)) == acc.index_map(i, j), acc.name
+
+
+def test_pallas_specs_clamp_inputs_to_the_grid_and_leave_outputs():
+    """An input whose interior map reads the blocks i - 1 and i + 1 is clamped
+    to block 0 and to block n/s - 1 at the two ends of the grid; an output
+    block keeps its map as declared."""
+    n, s = 64, 8
+    blocks = (
+        Block("lo", (s, 128), lambda i, j: (i - 1, 0), "a"),
+        Block("hi", (s, 128), lambda i, j: (i + 1, 0), "a"),
+        Block("out", (s, 128), lambda i, j: (i - 1, 0), is_output=True),
+    )
+    (lo, hi), (out,) = pallas_specs(blocks, {"a": (n, 128)})
+    last = n // s - 1
+    assert [int(lo.index_map(i, 0)[0]) for i in (0, 1, last)] == [0, 0, last - 1]
+    assert [int(hi.index_map(i, 0)[0]) for i in (0, last - 1, last)] == [1, last, last]
+    assert [int(out.index_map(i, 0)[0]) for i in (0, last)] == [-1, last - 1]
 
 
 def test_stencil_estimator_counts_strip_bytes():
@@ -161,6 +229,7 @@ def test_vmem_gate_holds_the_blocks_to_vmem_usable(block_mib, feasible):
         ("stencil25", (32, 32, 512), (32, 32)),  # stencil25.ensemble
         ("lbm_d3q15", (256, 256, 256), (8, 8)),  # lbm_d3q15.bulk
         ("lbm_d3q15", (32, 32, 256), (8, 8)),  # lbm_d3q15.ensemble
+        ("lbm_d3q27", (256, 256, 256), (8, 32)),  # lbm_twophase.bulk
     ],
 )
 def test_estimator_picks_of_the_benchmark_cells_stand(kernel, shape, pick):
@@ -168,8 +237,10 @@ def test_estimator_picks_of_the_benchmark_cells_stand(kernel, shape, pick):
     D3Q27 kernel moved nothing the estimator shares with them."""
     if kernel == "stencil25":
         got, _ = select_block(shape, 4, jnp.float32, machine=TPU_V5E)
-    else:
+    elif kernel == "lbm_d3q15":
         got, _ = lbm_select(shape, jnp.float32, machine=TPU_V5E)
+    else:
+        got, _ = lbm27_select(shape, jnp.float32, machine=TPU_V5E)
     assert got == pick
 
 
@@ -204,28 +275,6 @@ def test_lbm_allclose(shape, dtype, block):
     s = (slice(None), slice(1, -1), slice(1, -1), slice(None))
     np.testing.assert_allclose(fo[s], fr[s], **_tol(dtype))
     np.testing.assert_allclose(po[1:-1, 1:-1], pr[1:-1, 1:-1], **_tol(dtype))
-
-
-@pytest.mark.parametrize(
-    "shape, block", [((256, 256, 256), (8, 8)), ((256, 256, 256), (16, 16)), ((32, 32, 256), (8, 8))]
-)
-def test_lbm_config_space_matches_kernel_block_specs(shape, block):
-    """The estimator's candidate describes the kernel's own BlockSpecs, each
-    over whole x rows (no ghost-padded copy): nine tiles of f, nine of phase,
-    vel's centre, then f' and phi'."""
-    bits = 32
-    cfg = next(c for c in lbm_space(shape, bits) if c.meta["block"] == block)
-    names, in_specs, out_specs = lbm_block_specs(shape, block)
-    assert len(in_specs) == 9 + 9 + 1 == len(names)
-    assert len(cfg.accesses) == len(in_specs) + 2
-    assert [a.is_output for a in cfg.accesses] == [False] * len(in_specs) + [True, True]
-    assert [a.name for a in cfg.accesses[: len(names)]] == list(names)
-    nzb, nyb = shape[0] // block[0], shape[1] // block[1]
-    for acc, spec in zip(cfg.accesses, [*in_specs, *out_specs]):
-        assert tuple(acc.block_shape) == tuple(spec.block_shape), acc.name
-        assert acc.block_shape[-1] == shape[2], acc.name
-        for i, j in ((1, 1), (1, nyb - 2), (nzb - 2, nyb - 2)):  # interior: no clamp applies
-            assert tuple(int(v) for v in spec.index_map(i, j)) == acc.index_map(i, j), acc.name
 
 
 def test_lbm_mass_conservation():
@@ -355,23 +404,6 @@ def test_lbm27_momentum_is_conserved_without_force():
     for _ in range(5):
         g, u = hydro_step_ref(g, phase, u)
     assert float(jnp.abs(momentum(g) - before).max()) <= 1e-6
-
-
-def test_lbm27_config_space_matches_kernel_block_specs():
-    """The estimator's candidate describes the kernel's own BlockSpecs: nine
-    class centres, six z strips, six y strips, four corner pieces; phase's
-    centre, four strips, four corners; vel's centre; then g' and u'."""
-    shape, bits = (256, 256, 256), 32
-    cfg = next(c for c in lbm27_space(shape, bits) if c.meta["block"] == (16, 16))
-    names, in_specs, out_specs = lbm27_block_specs(shape, (16, 16), bits)
-    assert len(in_specs) == 9 + 6 + 6 + 4 + 1 + 4 + 4 + 1 == len(names)
-    assert len(cfg.accesses) == len(in_specs) + 2
-    assert [a.is_output for a in cfg.accesses] == [False] * len(in_specs) + [True, True]
-    assert [a.name for a in cfg.accesses[: len(names)]] == list(names)
-    for acc, spec in zip(cfg.accesses, [*in_specs, *out_specs]):
-        assert tuple(acc.block_shape) == tuple(spec.block_shape), acc.name
-        for i, j in ((1, 1), (5, 9), (14, 14)):  # interior: no clamp applies
-            assert tuple(int(v) for v in spec.index_map(i, j)) == acc.index_map(i, j), acc.name
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -214,10 +214,11 @@ def test_entry_pick_is_counted_once_per_trace_and_select_block_is_not(monkeypatc
     import jax.numpy as jnp
 
     from repro.core.machine import tpu_machine
-    from repro.kernels.stencil25 import ops, select_block, stencil25
+    from repro.kernels import entry
+    from repro.kernels.stencil25 import select_block, stencil25
 
     machine = tpu_machine("TPU v5 lite")
-    monkeypatch.setattr(ops, "device_machine", lambda: machine)
+    monkeypatch.setattr(entry, "device_machine", lambda: machine)
     series = "estimator.pick_seconds{entry=stencil25}"
 
     def count():
@@ -240,9 +241,9 @@ def test_twophase_step_opens_its_spans_and_counts_its_picks(monkeypatch):
     import jax.numpy as jnp
 
     from repro.core.machine import tpu_machine
+    from repro.kernels import entry
     from repro.kernels.lbm_d3q15 import init_fields
-    from repro.kernels.lbm_d3q15 import ops as lbm15_ops
-    from repro.kernels.lbm_d3q27 import equilibrium, ops, twophase_step
+    from repro.kernels.lbm_d3q27 import equilibrium, twophase_step
 
     f, phase, vel = init_fields((16, 16, 128))
     tracer = trace.enable()
@@ -251,8 +252,7 @@ def test_twophase_step_opens_its_spans_and_counts_its_picks(monkeypatch):
     assert [e["name"] for e in tracer.events].count("twophase_step.call") == 1
 
     machine = tpu_machine("TPU v5 lite")
-    monkeypatch.setattr(ops, "device_machine", lambda: machine)
-    monkeypatch.setattr(lbm15_ops, "device_machine", lambda: machine)
+    monkeypatch.setattr(entry, "device_machine", lambda: machine)
     series = "estimator.pick_seconds{entry=lbm_d3q27}"
 
     def count():

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import random
 
+import numpy as np
 import pytest
 
 from repro.core.machine import SINGLE_DEVICE_MESH, MeshSpec
@@ -267,7 +268,8 @@ if HAVE_HYPOTHESIS:
             deps = tuple(
                 f"n{j:02d}" for j in range(i) if draw(st.booleans())
             )
-            t = draw(st.floats(0.05, 2.0, allow_nan=False, width=32))
+            # width=32 draws float32 values, so the bounds must be float32s
+            t = draw(st.floats(float(np.float32(0.05)), 2.0, allow_nan=False, width=32))
             is_comm = comm_axes and draw(st.booleans())
             if is_comm:
                 nodes.append(_coll(f"n{i:02d}", t, draw(st.sampled_from(comm_axes)), deps))

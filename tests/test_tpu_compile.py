@@ -200,30 +200,25 @@ def test_entry_kernel_matches_the_benchmark_kernel_pattern(chip, monkeypatch, co
     an outer scope, and when the kernel itself is, in a jit of another name.
     The entry runs one kernel, or two in the two-phase step, whose D3Q15
     kernel the pattern must leave out and ``phase_kernel_pattern`` find."""
+    from repro.kernels import entry
     from repro.kernels.lbm_d3q15 import lbm_step
-    from repro.kernels.lbm_d3q15 import ops as lbm_ops
-    from repro.kernels.lbm_d3q27 import ops as lbm27_ops
     from repro.kernels.lbm_d3q27 import twophase_step
-    from repro.kernels.stencil25 import ops as stencil_ops
     from repro.kernels.stencil25 import stencil25
 
     struct, machine = chip
     spec = json.loads((CHIP_BENCH / "configs" / config / "config.json").read_text())
     limit = machine.vmem_usable
+    monkeypatch.setattr(entry, "device_machine", lambda: machine)
     if spec["kernel"] == "stencil25":
-        monkeypatch.setattr(stencil_ops, "device_machine", lambda: machine)
         fn = (lambda x: stencil25_pallas(x, r=4, block=(8, 8), vmem_limit_bytes=limit)
               ) if how == "kernel_in_scope" else (lambda x: stencil25(x, r=4))
         args = (struct(STENCIL),)
     elif spec["kernel"] == "lbm_d3q15":
-        monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
         fn = (lambda f, p, v: lbm_step_pallas(f, p, v, block=(8, 8), vmem_limit_bytes=limit)
               ) if how == "kernel_in_scope" else lbm_step
         nz, ny, nx = LBM
         args = struct((15, nz, ny, nx)), struct(LBM), struct((3, nz, ny, nx))
     else:
-        monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
-        monkeypatch.setattr(lbm27_ops, "device_machine", lambda: machine)
         fn = (lambda g, p, v: hydro_step_pallas(g, p, v, block=(8, 8), vmem_limit_bytes=limit)
               ) if how == "kernel_in_scope" else twophase_step
         nz, ny, nx = LBM
@@ -245,14 +240,12 @@ def test_lbm_entries_compile_without_x_pads(chip, monkeypatch, entry):
     """The D3Q15 kernel reads whole x rows and rotates them in lanes, so at
     the benchmark's 256^3 its entry compiles to the kernel alone, with no pad
     op, and the two-phase step, which runs it, holds no pad op either."""
+    from repro.kernels import entry as kernel_entry
     from repro.kernels.lbm_d3q15 import lbm_step
-    from repro.kernels.lbm_d3q15 import ops as lbm_ops
-    from repro.kernels.lbm_d3q27 import ops as lbm27_ops
     from repro.kernels.lbm_d3q27 import twophase_step
 
     struct, machine = chip
-    monkeypatch.setattr(lbm_ops, "device_machine", lambda: machine)
-    monkeypatch.setattr(lbm27_ops, "device_machine", lambda: machine)
+    monkeypatch.setattr(kernel_entry, "device_machine", lambda: machine)
     nz, ny, nx = LBM27
     f, phase, vel = struct((15, nz, ny, nx)), struct(LBM27), struct((3, nz, ny, nx))
     if entry == "lbm_step":
