@@ -13,14 +13,16 @@ from .ref import init_fields, lbm_step_ref
 
 def config_space(shape: tuple[int, int, int], dtype_bits: int):
     """Candidate PallasConfigs for `core.tpu_estimator` ranking: the kernel's
-    own inputs (:func:`kernel.input_blocks`: nine neighbour tiles of f and of
-    phase, vel's centre, all over whole x rows) plus the outputs f' and phi',
-    at every block of :data:`kernel.CANDIDATE_BLOCKS` that tiles the
-    grid."""
+    own inputs (:func:`kernel.input_blocks`: f's centre and each component's
+    z strip, y strip and corner piece it streams from, phase's centre and
+    four strips, vel's centre, all over whole x rows) plus the outputs f'
+    and phi', at every block of :data:`kernel.CANDIDATE_BLOCKS` that tiles
+    the grid."""
     nx = shape[2]
     return tiling.block_space(
         "lbm", shape, CANDIDATE_BLOCKS,
-        lambda b: (*input_blocks(b, nx), *output_blocks(b, nx)), dtype_bits, 350.0)
+        lambda b: (*input_blocks(b, nx, dtype_bits), *output_blocks(b, nx)), dtype_bits,
+        350.0)
 
 
 def select_block(

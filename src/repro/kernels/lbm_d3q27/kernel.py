@@ -3,7 +3,7 @@ two-phase solver (paper app 2, the lattice that feels the interface forces).
 
 TPU adaptation: tiles over (z, y); x is the lane dimension and every block
 holds whole x rows, so the periodic x neighbours are lane rotations of the
-row (no ghost-padded copy of the field, unlike :mod:`repro.kernels.lbm_d3q15`).
+row (no ghost-padded copy of the field).
 The 27 pdf components are grouped by their (cz, cy) stream direction into
 nine classes of three (``ref.DIRS`` order), so a class is one
 (3, bz, by, nx) block.  Pull streaming reads a
@@ -13,7 +13,8 @@ where cy != 0 and a (z, y) corner piece where both are.  The new phase field
 feeds the 27-point gradient and Laplacian, so it reads its centre, four
 strips and four corner pieces; the carried velocity only its centre tile.
 Strip heights come from :func:`repro.kernels.stencil25.kernel.strip_heights`
-at range 1: one z plane, and y rows on the dtype's sublane tile.
+at range 1: one z plane, and y rows on the dtype's sublane tile.  The D3Q15
+kernel reads the same layout (:mod:`repro.kernels.tiling`).
 :func:`input_blocks` and :func:`output_blocks` are the one description of
 these blocks, which :mod:`repro.kernels.tiling` turns into the BlockSpecs and
 the estimator's candidates.
@@ -28,18 +29,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..stencil25.kernel import strip_heights
-from ..tiling import Block, pallas_specs
+from ..tiling import SIDE_READ_BY, SIDES, Block, _plane, _yshift, halo_block, pallas_specs
 from .ref import DIRS, STEPS, WEIGHTS, TwoPhaseParams
 
 CLASSES = tuple((cz, cy) for cz in STEPS for cy in STEPS)  # class k = components 3k..3k+2
-SIDES = ("lo", "hi")
-SIDE_READ_BY = {1: "lo", -1: "hi"}  # pulling along c = +1 reads the rows below
-
-
-def _strip_index(side: str, i, k: int):
-    """Block index of the strip of height ``b / k`` that lies just below
-    (``side`` "lo") or just above ("hi") the centre tile ``i`` of height ``b``."""
-    return i * k - 1 if side == "lo" else (i + 1) * k
 
 
 def input_blocks(block: tuple[int, int], nx: int, dtype_bits: int):
@@ -49,22 +42,7 @@ def input_blocks(block: tuple[int, int], nx: int, dtype_bits: int):
     and four corners, then vel's centre.  ``operand`` is ``g`` (27, nz, ny, nx),
     ``phase`` (nz, ny, nx) or ``vel`` (3, nz, ny, nx).  The index maps are
     the interior ones."""
-    bz, by = block
-    hz, hy = strip_heights(1, block, dtype_bits)
-    kz, ky = bz // hz, by // hy
-
-    def piece(lead, zside, yside):
-        """Block shape and index map of the centre tile (side ``None``) or
-        the strip or corner on the given z and y sides, after ``lead``."""
-        shape = (*(n for n, _ in lead), hz if zside else bz, hy if yside else by, nx)
-
-        def index_map(i, j):
-            return (*(b for _, b in lead),
-                    i if zside is None else _strip_index(zside, i, kz),
-                    j if yside is None else _strip_index(yside, j, ky), 0)
-
-        return shape, index_map
-
+    heights = strip_heights(1, block, dtype_bits)
     out = []
     for k, (cz, cy) in enumerate(CLASSES):
         zs, ys = SIDE_READ_BY.get(cz), SIDE_READ_BY.get(cy)
@@ -72,12 +50,13 @@ def input_blocks(block: tuple[int, int], nx: int, dtype_bits: int):
                                      ("_zy", zs, ys)):
             if ("z" in suffix and zs is None) or ("y" in suffix and ys is None):
                 continue  # a side this class does not stream from
-            out.append(Block(f"g{k}{suffix}", *piece(((3, k),), zside, yside), "g"))
+            out.append(halo_block(f"g{k}{suffix}", "g", ((3, k),), block, heights, nx,
+                                  zside, yside))
     for zside in (None, *SIDES):
         for yside in (None, *SIDES):
             name = "phase" + (f"_z{zside}" if zside else "") + (f"_y{yside}" if yside else "")
-            out.append(Block(name, *piece((), zside, yside), "phase"))
-    out.append(Block("vel", (3, bz, by, nx), lambda i, j: (0, i, j, 0), "vel"))
+            out.append(halo_block(name, "phase", (), block, heights, nx, zside, yside))
+    out.append(halo_block("vel", "vel", ((3, 0),), block, heights, nx))
     return tuple(out)
 
 
@@ -86,33 +65,6 @@ def output_blocks(block: tuple[int, int], nx: int):
     bz, by = block
     return (Block("g_out", (27, bz, by, nx), lambda i, j: (0, i, j, 0), is_output=True),
             Block("vel_out", (3, bz, by, nx), lambda i, j: (0, i, j, 0), is_output=True))
-
-
-def _plane(centre, strip, z, dz: int):
-    """Plane z + dz (dz in -1, 0, 1) of a tile's z extension, as a value:
-    the centre block's own plane, or past its lower (upper) end the last
-    (first) plane of ``strip``, the z strip on that side."""
-    def at(ref, k):
-        return ref[k] if len(ref.shape) == 3 else ref[:, k]
-
-    bz = centre.shape[-3]
-    if dz == 0:
-        return at(centre, z)
-    own = at(centre, jnp.clip(z + dz, 0, bz - 1))
-    if dz < 0:
-        return jnp.where(z == 0, at(strip, strip.shape[-3] - 1), own)
-    return jnp.where(z == bz - 1, at(strip, 0), own)
-
-
-def _yshift(centre, strip, cy: int, hy: int):
-    """Rows y - cy of ``centre`` (y on axis -2), between it and its y strip
-    so that the concatenation stays on sublane tiles."""
-    by = centre.shape[-2]
-    if cy == 1:
-        return jnp.concatenate([strip, centre], axis=-2)[..., hy - 1 : hy - 1 + by, :]
-    if cy == -1:
-        return jnp.concatenate([centre, strip], axis=-2)[..., 1 : 1 + by, :]
-    return centre
 
 
 def _hydro_kernel(*refs, names, hy: int, nx: int, p: TwoPhaseParams):

@@ -8,6 +8,12 @@ declaration this module builds the Pallas BlockSpecs the kernel compiles with
 (:func:`block_space`, maps as declared: the paper's representative group away
 from the boundary, §III.D) and the estimator's pick among candidates
 (:func:`pick`, which the attention and WKV selections share).
+
+The two LBM kernels read the same z/y halo layout: a centre tile, and per
+field only the z strip, y strip and (z, y) corner piece on the sides it
+pulls from (:func:`halo_block`, :data:`SIDE_READ_BY`).  Their bodies walk a
+tile's z planes and put the halo planes and rows together with
+:func:`_plane` and :func:`_yshift`.
 """
 from __future__ import annotations
 
@@ -19,6 +25,10 @@ from jax.experimental import pallas as pl
 
 from ..core import tpu_estimator as te
 from ..core.machine import TPUMachine
+
+SIDES = ("lo", "hi")
+SIDE_READ_BY = {1: "lo", -1: "hi"}  # pulling along c = +1 reads the rows below
+
 
 @dataclass(frozen=True)
 class Block:
@@ -91,3 +101,54 @@ def pick(candidates: Sequence[te.PallasConfig], machine: TPUMachine, empty: str)
     if not candidates:
         raise ValueError(empty)
     return te.select_config(candidates, machine)
+
+
+def _strip_index(side: str, i, k: int):
+    """Block index of the strip of height ``b / k`` that lies just below
+    (``side`` "lo") or just above ("hi") the centre tile ``i`` of height ``b``."""
+    return i * k - 1 if side == "lo" else (i + 1) * k
+
+
+def halo_block(name: str, operand: str, lead, block: tuple[int, int],
+               heights: tuple[int, int], nx: int, zside=None, yside=None) -> Block:
+    """The input :class:`Block` of ``operand``'s centre tile at ``block``
+    (both sides ``None``), or of its strip or corner piece of the (hz, hy)
+    ``heights`` on the given z and y sides, over whole x rows.  ``lead``
+    gives the (extent, block index) of each axis before z."""
+    (bz, by), (hz, hy) = block, heights
+    kz, ky = bz // hz, by // hy
+    shape = (*(n for n, _ in lead), hz if zside else bz, hy if yside else by, nx)
+
+    def index_map(i, j):
+        return (*(b for _, b in lead),
+                i if zside is None else _strip_index(zside, i, kz),
+                j if yside is None else _strip_index(yside, j, ky), 0)
+
+    return Block(name, shape, index_map, operand)
+
+
+def _plane(centre, strip, z, dz: int):
+    """Plane z + dz (dz in -1, 0, 1) of a tile's z extension, as a value:
+    the centre block's own plane, or past its lower (upper) end the last
+    (first) plane of ``strip``, the z strip on that side."""
+    def at(ref, k):
+        return ref[k] if len(ref.shape) == 3 else ref[:, k]
+
+    bz = centre.shape[-3]
+    if dz == 0:
+        return at(centre, z)
+    own = at(centre, jnp.clip(z + dz, 0, bz - 1))
+    if dz < 0:
+        return jnp.where(z == 0, at(strip, strip.shape[-3] - 1), own)
+    return jnp.where(z == bz - 1, at(strip, 0), own)
+
+
+def _yshift(centre, strip, cy: int, hy: int):
+    """Rows y - cy of ``centre`` (y on axis -2), between it and its y strip
+    so that the concatenation stays on sublane tiles."""
+    by = centre.shape[-2]
+    if cy == 1:
+        return jnp.concatenate([strip, centre], axis=-2)[..., hy - 1 : hy - 1 + by, :]
+    if cy == -1:
+        return jnp.concatenate([centre, strip], axis=-2)[..., 1 : 1 + by, :]
+    return centre

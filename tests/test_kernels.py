@@ -105,10 +105,11 @@ def test_stencil_strip_heights(r, block, dtype_bits, heights):
 
 
 # (kernel, shape, block), with each kernel's count of inputs: stencil25's
-# centre and four strips; D3Q15's nine tiles of f, nine of phase and vel's
-# centre; D3Q27's nine class centres, six z strips, six y strips, four corner
-# pieces, phase's centre, four strips and four corners, and vel's centre
-BLOCK_DECLARATIONS = {"stencil25": 5, "lbm_d3q15": 9 + 9 + 1,
+# centre and four strips; D3Q15's centre of f, ten z strips, ten y strips and
+# eight corner pieces of its components, phase's centre and four strips, and
+# vel's centre; D3Q27's nine class centres, six z strips, six y strips, four
+# corner pieces, phase's centre, four strips and four corners, and vel's centre
+BLOCK_DECLARATIONS = {"stencil25": 5, "lbm_d3q15": 1 + 10 + 10 + 8 + 1 + 4 + 1,
                       "lbm_d3q27": 9 + 6 + 6 + 4 + 1 + 4 + 4 + 1}
 
 
@@ -120,6 +121,7 @@ BLOCK_DECLARATIONS = {"stencil25": 5, "lbm_d3q15": 9 + 9 + 1,
         ("lbm_d3q15", (256, 256, 256), (16, 16)),
         ("lbm_d3q15", (32, 32, 256), (8, 8)),
         ("lbm_d3q27", (256, 256, 256), (16, 16)),
+        ("lbm_d3q15", (256, 256, 256), (8, 32)),
     ],
 )
 def test_config_space_matches_kernel_block_specs(monkeypatch, kernel, shape, block):
@@ -206,6 +208,34 @@ def test_stencil_estimator_counts_strip_bytes():
     assert ranked[0] == (32, 32)
 
 
+def test_lbm_d3q15_estimator_counts_strip_bytes():
+    """``hbm_bytes`` of the (8, 32) candidate at 256^3 is the hand count:
+    every block index moves with j, so each of the 35 inputs is fetched and
+    both outputs written at all 32 x 8 grid steps.  One z plane and 8 y rows
+    make the strips (``strip_heights``); a leading extent of 1 picks an f
+    component, whose (by, nx) or (8, nx) rows need no tile padding."""
+    cands = lbm_space((256, 256, 256), 32)
+    cfg = next(c for c in cands if c.meta["block"] == (8, 32))
+    f32, bz, by, nx = 4, 8, 32, 256
+    per_step = (
+        15 * bz * by * nx * f32  # f centre, 3,932,160
+        + 10 * 1 * by * nx * f32  # f z strips, (1, 1, 32, 256), 327,680
+        + 10 * bz * 8 * nx * f32  # f y strips, (1, 8, 8, 256), 655,360
+        + 8 * 1 * 8 * nx * f32  # f corners, (1, 1, 8, 256), 65,536
+        + bz * by * nx * f32  # phase centre, 262,144
+        + 2 * 1 * by * nx * f32  # phase z strips, 65,536
+        + 2 * bz * 8 * nx * f32  # phase y strips, 131,072
+        + 3 * bz * by * nx * f32  # vel centre, 786,432
+        + 15 * bz * by * nx * f32  # f_out, 3,932,160
+        + bz * by * nx * f32  # phase_out, 262,144
+    )
+    assert per_step == 10_420_224
+    est = te.estimate(cfg, TPU_V5E)
+    assert est.hbm_bytes == 32 * 8 * per_step
+    ranked = [c.meta["block"] for c, _ in te.rank_configs(cands, TPU_V5E)]
+    assert ranked[0] == (8, 32)
+
+
 @pytest.mark.parametrize("block_mib, feasible", [(90, True), (100, True), (102, False)])
 def test_vmem_gate_holds_the_blocks_to_vmem_usable(block_mib, feasible):
     """The gate is the double-buffered blocks against ``vmem_usable``, the
@@ -227,14 +257,15 @@ def test_vmem_gate_holds_the_blocks_to_vmem_usable(block_mib, feasible):
     [
         ("stencil25", (1024, 1024, 512), (32, 32)),  # stencil25.bulk
         ("stencil25", (32, 32, 512), (32, 32)),  # stencil25.ensemble
-        ("lbm_d3q15", (256, 256, 256), (8, 8)),  # lbm_d3q15.bulk
-        ("lbm_d3q15", (32, 32, 256), (8, 8)),  # lbm_d3q15.ensemble
+        ("lbm_d3q15", (256, 256, 256), (8, 32)),  # lbm_d3q15.bulk
+        ("lbm_d3q15", (32, 32, 256), (8, 32)),  # lbm_d3q15.ensemble
         ("lbm_d3q27", (256, 256, 256), (8, 32)),  # lbm_twophase.bulk
     ],
 )
 def test_estimator_picks_of_the_benchmark_cells_stand(kernel, shape, pick):
-    """The picks of the chip benchmark's accepted cells stand: adding the
-    D3Q27 kernel moved nothing the estimator shares with them."""
+    """The picks of the chip benchmark's accepted cells stand.  The D3Q15
+    kernel's strips make its wider blocks cheaper in bytes, so it picks
+    (8, 32), as the D3Q27 kernel does."""
     if kernel == "stencil25":
         got, _ = select_block(shape, 4, jnp.float32, machine=TPU_V5E)
     elif kernel == "lbm_d3q15":
@@ -265,9 +296,19 @@ def test_stencil_estimator_selection_valid():
     assert np.isfinite(np.asarray(out)).all()
 
 
-@pytest.mark.parametrize("shape", [(16, 16, 32), (16, 32, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32])
-@pytest.mark.parametrize("block", [(8, 8), (4, 16)])
+LBM_SHAPES = [(16, 16, 32), (16, 32, 64), (24, 96, 128), (48, 48, 128)]
+LBM_BLOCKS = [(8, 8), (4, 16), (8, 32), (16, 16)]
+
+
+# the first two shapes at the first two blocks; then tiles with interior
+# neighbours in z and in y whose y strips (8 rows) are thinner than the tile
+@pytest.mark.parametrize(
+    "shape, dtype, block",
+    [
+        pytest.param(LBM_SHAPES[s], jnp.float32, LBM_BLOCKS[b], id=f"block{b}-float32-shape{s}")
+        for b, s in ((0, 0), (0, 1), (1, 0), (1, 1), (2, 2), (3, 3))
+    ],
+)
 def test_lbm_allclose(shape, dtype, block):
     f, phase, vel = init_fields(shape, dtype=dtype)
     fo, po = lbm_step(f, phase, vel, block=block, interpret=True)
@@ -322,16 +363,24 @@ def test_lbm27_kernel_allclose(shape, block):
     _lbm27_close(uo, ur)
 
 
-@pytest.mark.parametrize("block", LBM27_BLOCKS)
-@pytest.mark.parametrize("shape", LBM27_SHAPES)
-def test_twophase_step_allclose(shape, block):
+# both kernels at each block of LBM27_BLOCKS; then both at their 256^3 picks,
+# (8, 32), on a domain with interior tiles in z and y
+@pytest.mark.parametrize(
+    "shape, block, phase_block",
+    [
+        *(pytest.param(shape, block, block, id=f"shape{s}-block{b}")
+          for s, shape in enumerate(LBM27_SHAPES) for b, block in enumerate(LBM27_BLOCKS)),
+        pytest.param((24, 96, 128), (8, 32), (8, 32), id="shape24x96x128-block8x32-phase8x32"),
+    ],
+)
+def test_twophase_step_allclose(shape, block, phase_block):
     """The coupled entry (D3Q15 then D3Q27, one jit) against the coupled
     oracle from the droplet, outside the two-cell shell: the D3Q15 step
     leaves one cell undefined and the D3Q27 kernel reads phi' one further."""
     f, phase, vel = init_fields(shape, seed=5)
     g = equilibrium(vel)
     params = TwoPhaseParams()
-    out = twophase_step(f, g, phase, vel, params=params, block=block, phase_block=block,
+    out = twophase_step(f, g, phase, vel, params=params, block=block, phase_block=phase_block,
                         interpret=True)
     for got, want in zip(out, jax.jit(twophase_step_ref, static_argnums=4)(f, g, phase, vel, params)):
         _lbm27_close(got, want)

@@ -3,13 +3,15 @@
 Nothing runs: the chip's own compiler accepts or refuses each kernel, which is
 what interpret-mode tests cannot show (VMEM limits, Mosaic's block-shape rule,
 ops Mosaic cannot lower).  Shapes are the ones ``chip_smoke.py`` runs, and
-the chip benchmark's LBM shapes: the two-phase cell's 256^3 and the LBM
+the chip benchmark's LBM shapes: the bulk cells' 256^3 and the LBM
 ensemble's 32x32x256.  The
 topology is described inside a fixture, never at import: only one process at
 a time may load the TPU library.
 """
 from __future__ import annotations
 
+import base64
+import hashlib
 import json
 import re
 import sys
@@ -39,7 +41,7 @@ from repro.kernels.wkv.kernel import wkv_pallas
 CHIP_BENCH = Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
 STENCIL = (256, 256, 512)  # r=4, f32
 LBM = (128, 128, 128)  # f32
-LBM27 = (256, 256, 256)  # f32, the two-phase cell's domain
+LBM27 = (256, 256, 256)  # f32, the bulk LBM cells' domain (D3Q15 and two-phase)
 LBM_ENSEMBLE = (32, 32, 256)  # f32, the LBM ensemble cell's domain
 ATTN = (4, 32, 8, 8192, 128)  # b, hq, hkv, s, d; bf16
 WKV = (64, 4096, 64)  # BH, S, K; f32
@@ -133,6 +135,12 @@ def test_lbm_ensemble_candidate_compiles_iff_feasible(chip, cfg):
             == te.estimate(cfg, machine).feasible)
 
 
+@pytest.mark.parametrize("cfg", lbm_space(LBM27, 32), ids=lambda c: c.name)
+def test_lbm_bulk_candidate_compiles_iff_feasible(chip, cfg):
+    struct, machine = chip
+    assert _lbm(struct, machine, cfg.meta["block"], LBM27) == te.estimate(cfg, machine).feasible
+
+
 @pytest.mark.parametrize("cfg", lbm27_space(LBM27, 32), ids=lambda c: c.name)
 def test_lbm27_candidate_compiles_iff_feasible(chip, cfg):
     struct, machine = chip
@@ -170,6 +178,42 @@ def test_estimator_pick_compiles(chip, kernel):
             lambda r, k, v, w, u: wkv_pallas(r, k, v, w, u, chunk=chunk, vmem_limit_bytes=limit),
             a, a, a, a, struct((K,)),
         )
+
+
+def _lowered_digest(lowered) -> str:
+    """sha256 of ``lowered``'s StableHLO with its Mosaic kernel body decoded,
+    both printed without source locations: what the kernel is, not where
+    its Python lines sit."""
+    module = lowered.compiler_ir("stablehlo")
+    text = module.operation.get_asm(enable_debug_info=False)
+    (body,) = re.findall(r"\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22", text)
+    module.context.allow_unregistered_dialects = True
+    kernel = type(module).parse(base64.b64decode(body), context=module.context)
+    text = text.replace(body, "") + kernel.operation.get_asm(enable_debug_info=False)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+# the D3Q27 kernel's lowered module at 256^3; a change meant to alter the
+# kernel updates these digests, any other change leaves them
+LBM27_DIGESTS = {
+    (8, 32): "c558413eafc3ed9d0fe254fa8c1999ea72546b0a791d5e71dca1d966b9fbe754",
+    (8, 8): "1198f676108a788a46b936941c58bf2bc3ec1bb8579e5ea9f35efb8b3d5e7a79",
+    (16, 16): "1b091b83b1a0241cf7915a63cb1be460c9a9dc21b2137242e90c63177d63944c",
+}
+
+
+@pytest.mark.parametrize("block", list(LBM27_DIGESTS), ids=lambda b: f"{b[0]}x{b[1]}")
+def test_lbm27_lowered_kernel_is_pinned(chip, block):
+    """The D3Q27 kernel shares its halo layout and plane helpers with the
+    D3Q15 kernel (``kernels/tiling.py``); a change to the shared code that is
+    meant for the D3Q15 kernel leaves the D3Q27 kernel's lowered module as
+    it was, apart from source locations."""
+    struct, machine = chip
+    nz, ny, nx = LBM27
+    lowered = jax.jit(lambda g, p, v: hydro_step_pallas(
+        g, p, v, block=block, vmem_limit_bytes=machine.vmem_usable)).lower(
+        struct((27, nz, ny, nx)), struct(LBM27), struct((3, nz, ny, nx)))
+    assert _lowered_digest(lowered) == LBM27_DIGESTS[block]
 
 
 def _kernel_instructions(lowered) -> list[str]:
