@@ -29,7 +29,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..stencil25.kernel import strip_heights
-from ..tiling import SIDE_READ_BY, SIDES, Block, _plane, _yshift, halo_block, pallas_specs
+from ..tiling import (SIDE_READ_BY, SIDES, Block, _plane, _ystrip, _yshift, edges, ghost_blocks,
+                      halo_block, pallas_specs)
 from .ref import DIRS, WEIGHTS
 
 # the (bz, by) blocks the estimator ranks, for this kernel and the D3Q27 one
@@ -44,6 +45,7 @@ def _lbm_kernel(*refs, names, hy: int, nx: int, tau: float, width: float):
     ins = dict(zip(names, refs))
     f_out_ref, phase_out_ref = refs[len(names)], refs[len(names) + 1]
     phase = ins["phase"]
+    at_edge = edges(names)
 
     def component(name, q=0):
         """Component q of the f block ``name``, or None where there is none."""
@@ -59,8 +61,9 @@ def _lbm_kernel(*refs, names, hy: int, nx: int, tau: float, width: float):
         for q, (cx, cy, cz) in enumerate(DIRS):
             row = _plane(component("f", q), component(f"f{q}_z"), z, -cz)
             if cy:
-                row = _yshift(row, _plane(component(f"f{q}_y"), component(f"f{q}_zy"), z, -cz),
-                              cy, hy)
+                strip = _ystrip(ins, f"f{q}_y", f"f{q}_zy" if cz else None, z, -cz,
+                                at_edge[SIDE_READ_BY[cy]], part=lambda ref: ref.at[0])
+                row = _yshift(row, strip, cy, hy)
             pulled.append(xshift(row, cx))
         phi_new = pulled[0]
         for q in range(1, 15):
@@ -68,8 +71,8 @@ def _lbm_kernel(*refs, names, hy: int, nx: int, tau: float, width: float):
         # 7pt central differences on the input phase
         prow = phase[z]
         gx = 0.5 * (xshift(prow, -1) - xshift(prow, 1))
-        gy = 0.5 * (_yshift(prow, ins["phase_yhi"][z], -1, hy)
-                    - _yshift(prow, ins["phase_ylo"][z], 1, hy))
+        gy = 0.5 * (_yshift(prow, _ystrip(ins, "phase_yhi", None, z, 0, at_edge["hi"]), -1, hy)
+                    - _yshift(prow, _ystrip(ins, "phase_ylo", None, z, 0, at_edge["lo"]), 1, hy))
         gz = 0.5 * (_plane(phase, ins["phase_zhi"], z, 1) - _plane(phase, ins["phase_zlo"], z, -1))
         inv_norm = jax.lax.rsqrt(gx * gx + gy * gy + gz * gz + 1e-12)
         nxv, nyv, nzv = gx * inv_norm, gy * inv_norm, gz * inv_norm
@@ -130,16 +133,22 @@ def lbm_step_pallas(
     block: tuple[int, int] = (8, 8),
     vmem_limit_bytes: int | None = None,
     interpret: bool = False,
+    ghosts: dict | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One LB interface-tracking step; valid outside the one-cell z/y shell,
-    where the clamped halo stands in for the neighbours."""
+    where the clamped halo stands in for the neighbours.  On a chip's block
+    of a domain split in y, ``ghosts`` holds the rows next to its y edges
+    (:func:`repro.kernels.tiling.exchange`), which the edge tiles read in
+    place of the clamped y strips."""
     _, nz, ny, nx = f.shape
     bz, by = block
     if nz % bz or ny % by:
         raise ValueError(f"grid {(nz, ny, nx)} not divisible by block {block}")
     bits = f.dtype.itemsize * 8
-    fields = {"f": f, "phase": phase, "vel": vel}
+    fields = {"f": f, "phase": phase, "vel": vel, **(ghosts or {})}
     inputs = input_blocks(block, nx, bits)
+    if ghosts:
+        inputs = (*inputs, *ghost_blocks(inputs))
     in_specs, out_specs = pallas_specs((*inputs, *output_blocks(block, nx)),
                                        {op: a.shape for op, a in fields.items()})
     kernel = functools.partial(_lbm_kernel, names=tuple(b.name for b in inputs),

@@ -11,24 +11,27 @@ from .kernel import CANDIDATE_BLOCKS, input_blocks, lbm_step_pallas, output_bloc
 from .ref import init_fields, lbm_step_ref
 
 
-def config_space(shape: tuple[int, int, int], dtype_bits: int):
+def config_space(shape: tuple[int, int, int], dtype_bits: int, mesh=None):
     """Candidate PallasConfigs for `core.tpu_estimator` ranking: the kernel's
     own inputs (:func:`kernel.input_blocks`: f's centre and each component's
     z strip, y strip and corner piece it streams from, phase's centre and
     four strips, vel's centre, all over whole x rows) plus the outputs f'
     and phi', at every block of :data:`kernel.CANDIDATE_BLOCKS` that tiles
-    the grid."""
+    the grid.  With a ``mesh`` the domain of ``shape`` is split in y over
+    its chips (:mod:`repro.kernels.tiling`): the candidates tile one chip's
+    block and read the ghosts too."""
     nx = shape[2]
     return tiling.block_space(
-        "lbm", shape, CANDIDATE_BLOCKS,
-        lambda b: (*input_blocks(b, nx, dtype_bits), *output_blocks(b, nx)), dtype_bits,
+        "lbm", tiling.local_shape(shape, mesh), CANDIDATE_BLOCKS,
+        lambda b: (*tiling.with_ghosts(input_blocks(b, nx, dtype_bits), mesh),
+                   *output_blocks(b, nx)), dtype_bits,
         350.0)
 
 
 def select_block(
-    shape: tuple[int, int, int], dtype=jnp.float32, *, machine: TPUMachine
+    shape: tuple[int, int, int], dtype=jnp.float32, *, machine: TPUMachine, mesh=None
 ) -> tuple[tuple[int, int], te.TPUEstimate]:
-    cands = config_space(shape, jnp.dtype(dtype).itemsize * 8)
+    cands = config_space(shape, jnp.dtype(dtype).itemsize * 8, mesh)
     cfg, est = tiling.pick(cands, machine, f"no candidate block tiles divide grid {shape}")
     return cfg.meta["block"], est
 

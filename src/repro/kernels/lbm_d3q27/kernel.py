@@ -29,7 +29,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..stencil25.kernel import strip_heights
-from ..tiling import SIDE_READ_BY, SIDES, Block, _plane, _yshift, halo_block, pallas_specs
+from ..tiling import (SIDE_READ_BY, SIDES, Block, _plane, _ystrip, _yshift, edges, ghost_blocks,
+                      halo_block, pallas_specs)
 from .ref import DIRS, STEPS, WEIGHTS, TwoPhaseParams
 
 CLASSES = tuple((cz, cy) for cz in STEPS for cy in STEPS)  # class k = components 3k..3k+2
@@ -75,17 +76,18 @@ def _hydro_kernel(*refs, names, hy: int, nx: int, p: TwoPhaseParams):
     ins = dict(zip(names, refs))
     g_out_ref, vel_out_ref = refs[len(names)], refs[len(names) + 1]
     bz, by = ins["phase"].shape[0], ins["phase"].shape[1]
+    at_edge = edges(names)
 
     def phase_rows(z, dz):
         """Plane z + dz of the new phase over y rows [-hy, by + hy) of the
         tile: the y strips' planes around the centre's."""
         zside = {-1: "_zlo", 0: "", 1: "_zhi"}[dz]
-        parts = []
-        for part in ("_ylo", "", "_yhi"):
-            centre = ins["phase" + part]
-            strip = ins["phase" + zside + part] if dz else None
-            parts.append(_plane(centre, strip, z, dz))
-        return jnp.concatenate(parts, axis=0)
+        corner = (lambda part: "phase" + zside + part) if dz else (lambda part: None)
+        return jnp.concatenate([
+            _ystrip(ins, "phase_ylo", corner("_ylo"), z, dz, at_edge["lo"]),
+            _plane(ins["phase"], ins["phase" + zside] if dz else None, z, dz),
+            _ystrip(ins, "phase_yhi", corner("_yhi"), z, dz, at_edge["hi"]),
+        ], axis=0)
 
     def xshift(a, cx: int):
         """a(x - cx) at x, periodic: a lane rotation of the whole row."""
@@ -112,7 +114,9 @@ def _hydro_kernel(*refs, names, hy: int, nx: int, p: TwoPhaseParams):
         for k, (cz, cy) in enumerate(CLASSES):
             cls = _plane(ins[f"g{k}"], ins.get(f"g{k}_z"), z, -cz)
             if cy:
-                cls = _yshift(cls, _plane(ins[f"g{k}_y"], ins.get(f"g{k}_zy"), z, -cz), cy, hy)
+                strip = _ystrip(ins, f"g{k}_y", f"g{k}_zy" if cz else None, z, -cz,
+                                at_edge[SIDE_READ_BY[cy]])
+                cls = _yshift(cls, strip, cy, hy)
             for m in range(3):
                 pulled.append(xshift(cls[m], DIRS[3 * k + m][0]))
 
@@ -186,16 +190,20 @@ def hydro_step_pallas(
     block: tuple[int, int] = (8, 8),
     vmem_limit_bytes: int | None = None,
     interpret: bool = False,
+    ghosts: dict | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One D3Q27 hydrodynamic step -> (g', u'); valid outside the one-cell
-    z/y shell, where the clamped halo stands in for the neighbours."""
+    z/y shell, where the clamped halo stands in for the neighbours.
+    ``ghosts`` as in :func:`repro.kernels.lbm_d3q15.kernel.lbm_step_pallas`."""
     _, nz, ny, nx = g.shape
     bz, by = block
     if nz % bz or ny % by:
         raise ValueError(f"grid {(nz, ny, nx)} not divisible by block {block}")
     bits = g.dtype.itemsize * 8
-    fields = {"g": g, "phase": phase, "vel": vel}
+    fields = {"g": g, "phase": phase, "vel": vel, **(ghosts or {})}
     inputs = input_blocks(block, nx, bits)
+    if ghosts:
+        inputs = (*inputs, *ghost_blocks(inputs))
     in_specs, out_specs = pallas_specs((*inputs, *output_blocks(block, nx)),
                                        {op: a.shape for op, a in fields.items()})
     kernel = functools.partial(_hydro_kernel, names=tuple(b.name for b in inputs),

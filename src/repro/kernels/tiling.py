@@ -14,6 +14,14 @@ field only the z strip, y strip and (z, y) corner piece on the sides it
 pulls from (:func:`halo_block`, :data:`SIDE_READ_BY`).  Their bodies walk a
 tile's z planes and put the halo planes and rows together with
 :func:`_plane` and :func:`_yshift`.
+
+A domain too large for one chip is split in y over a chain of chips (a mesh
+axis :data:`AXIS`), each holding a block of whole z planes.  An edge tile of
+a chip's block reads, in place of its y strip, the one row next to the seam
+that the neighbouring chip sent (:func:`exchange`): a ghost of y extent 1
+declared beside each y strip and corner piece (:func:`ghost_blocks`), and
+taken in the body by :func:`_ystrip`.  Only the rows some y strip reads
+are sent (:func:`ghost_rows`).
 """
 from __future__ import annotations
 
@@ -21,6 +29,7 @@ from dataclasses import dataclass
 from typing import Callable, Sequence
 
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 
 from ..core import tpu_estimator as te
@@ -28,6 +37,8 @@ from ..core.machine import TPUMachine
 
 SIDES = ("lo", "hi")
 SIDE_READ_BY = {1: "lo", -1: "hi"}  # pulling along c = +1 reads the rows below
+AXIS = "y"  # the mesh axis a domain is split along, a chain of chips
+GHOST = "_ghost"  # suffix of a ghost block's name, and of a ghost operand's before its side
 
 
 @dataclass(frozen=True)
@@ -41,6 +52,8 @@ class Block:
     index_map: Callable[[int, int], tuple]
     operand: str | None = None
     is_output: bool = False
+    lead: tuple[tuple[int, int], ...] = ()  # (extent, block index) of each axis before z
+    yside: str | None = None  # the side of a y strip or corner piece
 
 
 def pallas_specs(blocks: Sequence[Block], extents: dict[str, tuple[int, ...]]):
@@ -124,7 +137,7 @@ def halo_block(name: str, operand: str, lead, block: tuple[int, int],
                 i if zside is None else _strip_index(zside, i, kz),
                 j if yside is None else _strip_index(yside, j, ky), 0)
 
-    return Block(name, shape, index_map, operand)
+    return Block(name, shape, index_map, operand, lead=tuple(lead), yside=yside)
 
 
 def _plane(centre, strip, z, dz: int):
@@ -152,3 +165,110 @@ def _yshift(centre, strip, cy: int, hy: int):
     if cy == -1:
         return jnp.concatenate([centre, strip], axis=-2)[..., 1 : 1 + by, :]
     return centre
+
+
+def _ystrip(ins, name: str, corner: str | None, z, dz: int, at_edge, part=lambda ref: ref):
+    """Plane z + dz of the y strip ``ins[name]``, past the tile's z ends its
+    corner piece ``ins[corner]`` (:func:`_plane`), each ref seen through
+    ``part``.  Where the kernel reads ghosts (``at_edge`` not ``None``), an
+    edge tile of the chip's block takes the ghost row of the same plane in
+    its place, over all the strip's rows: the strip's row next to the tile,
+    the one :func:`_yshift` reads, is then the neighbouring chip's."""
+    def plane(n, c):
+        return _plane(part(ins[n]), None if c is None else part(ins[c]), z, dz)
+
+    strip = plane(name, corner)
+    if at_edge is None:
+        return strip
+    ghost = plane(name + GHOST, None if corner is None else corner + GHOST)
+    return jnp.where(at_edge, jnp.broadcast_to(ghost, strip.shape), strip)
+
+
+def edges(names) -> dict:
+    """``{side: whether this grid step is the chip's edge tile on that
+    side}`` for a kernel whose inputs ``names`` hold ghosts; ``None`` on
+    each side for one that reads none."""
+    if not any(n.endswith(GHOST) for n in names):
+        return dict.fromkeys(SIDES)
+    j = pl.program_id(1)
+    return {"lo": j == 0, "hi": j == pl.num_programs(1) - 1}
+
+
+def ghost_operand(operand: str, side: str) -> str:
+    return f"{operand}{GHOST}_{side}"
+
+
+def ghost_rows(blocks: Sequence[Block]) -> dict[tuple[str, str], tuple[int, ...]]:
+    """``{(operand, side): elements of its leading axis}``: what the y strips
+    and corner pieces among ``blocks`` read on each side, so what the chip
+    on that side sends; ``()`` for an operand with no leading axis."""
+    rows: dict[tuple[str, str], set[int]] = {}
+    for b in blocks:
+        if b.yside is not None:
+            got = rows.setdefault((b.operand, b.yside), set())
+            for n, k in b.lead:
+                got.update(range(n * k, n * (k + 1)))
+    return {key: tuple(sorted(e)) for key, e in rows.items()}
+
+
+def ghost_blocks(blocks: Sequence[Block]) -> tuple[Block, ...]:
+    """The ghost of each y strip and corner piece among ``blocks``: the same
+    z extent and z index over one row, of the operand
+    :func:`ghost_operand` names, whose leading axis holds :func:`ghost_rows`'
+    elements in order."""
+    rows = ghost_rows(blocks)
+    out = []
+    for b in blocks:
+        if b.yside is None:
+            continue
+        lead = tuple(rows[(b.operand, b.yside)].index(n * k) // n for n, k in b.lead)
+        out.append(Block(b.name + GHOST, (*b.shape[:-2], 1, b.shape[-1]),
+                         lambda i, j, m=b.index_map, lead=lead: (*lead, m(i, j)[-3], 0, 0),
+                         ghost_operand(b.operand, b.yside)))
+    return tuple(out)
+
+
+def with_ghosts(blocks: Sequence[Block], mesh) -> tuple[Block, ...]:
+    """``blocks``, and their ghosts where the domain is split over ``mesh``."""
+    return (*blocks, *(ghost_blocks(blocks) if mesh is not None else ()))
+
+
+def local_shape(shape: tuple[int, int, int], mesh) -> tuple[int, int, int]:
+    """The (nz, ny, nx) block one chip holds of a domain of ``shape`` split
+    in y over ``mesh`` (the whole domain without one)."""
+    if mesh is None:
+        return tuple(shape)
+    nz, ny, nx = shape
+    chips = mesh.shape[AXIS]
+    if ny % chips:
+        raise ValueError(f"ny {ny} does not split over {chips} chips")
+    return nz, ny // chips, nx
+
+
+def exchange(fields: dict, rows: dict[tuple[str, str], tuple[int, ...]]) -> dict:
+    """Inside a ``shard_map`` over :data:`AXIS`: the ghost operand of each
+    (operand, side) of ``rows`` whose operand is among ``fields``, as
+    :func:`ghost_blocks` reads it.  Each chip sends its last y row to the
+    chip above, whose "lo" ghost it is, and its first row to the chip below
+    (the "hi" ghost there), of the leading elements ``rows`` names.  The
+    seams form a chain, not a ring: the first chip's "lo" ghost and the
+    last's "hi" ghost are zeros, left in the domain's undefined shell."""
+    chips = lax.axis_size(AXIS)
+    perm = {"lo": [(k, k + 1) for k in range(chips - 1)],
+            "hi": [(k + 1, k) for k in range(chips - 1)]}
+    out = {}
+    for (operand, side), elems in rows.items():
+        if operand not in fields:
+            continue
+        a = fields[operand]
+        y = a.shape[-2] - 1 if side == "lo" else 0
+        row = lax.slice_in_dim(a, y, y + 1, axis=a.ndim - 2)
+        if elems:
+            row = jnp.concatenate([row[e : e + 1] for e in elems])
+        out[ghost_operand(operand, side)] = lax.ppermute(row, AXIS, perm[side])
+    return out
+
+
+def sent_bytes(ghosts: dict) -> int:
+    """Bytes of ``ghosts``: what one chip with a neighbour on each side sends."""
+    return sum(g.size * g.dtype.itemsize for g in ghosts.values())

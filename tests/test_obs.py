@@ -268,6 +268,48 @@ def test_twophase_step_opens_its_spans_and_counts_its_picks(monkeypatch):
     assert count() == before + 1
 
 
+def test_mesh_step_gauges_the_bytes_its_exchange_sends():
+    """Tracing the two-phase step over a chain of four chips sets
+    ``lattice.halo_bytes{entry=twophase_step}`` to the bytes one chip with a
+    neighbour on each side sends a step: 16 one-row planes a direction (5 f
+    components, phase, 9 g components, phi'), nz x nx f32 each, as the
+    exchange helper's ghosts hold."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import AxisType, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import tiling
+    from repro.kernels.lbm_d3q15 import kernel as lbm15
+    from repro.kernels.lbm_d3q27 import kernel as lbm27
+    from repro.kernels.lbm_d3q27 import twophase_step
+
+    mesh = jax.make_mesh((4,), (tiling.AXIS,), axis_types=(AxisType.Auto,),
+                         devices=jax.devices()[:4])
+    nz, ny, nx = shape = (8, 32, 256)
+
+    def split(*lead):
+        return jax.ShapeDtypeStruct((*lead, *shape), jnp.float32, sharding=NamedSharding(
+            mesh, P(*(None,) * (len(lead) + 1), tiling.AXIS, None)))
+
+    gauge = metrics.gauge("lattice.halo_bytes", entry="twophase_step")
+    gauge.set(0)
+    twophase_step.trace(split(15), split(27), split(), split(3), block=(8, 8),
+                        phase_block=(8, 8), interpret=True, mesh=mesh)
+    assert gauge.value == 2 * 16 * nz * nx * 4
+
+    local = {"f": split(15), "g": split(27), "phase": split()}
+    rows = {**tiling.ghost_rows(lbm15.input_blocks((8, 8), nx, 32)),
+            **tiling.ghost_rows(lbm27.input_blocks((8, 8), nx, 32))}
+    ghosts = jax.eval_shape(jax.shard_map(
+        lambda fields: tiling.exchange(fields, rows), mesh=mesh,
+        in_specs=({k: P(*(None,) * (v.ndim - 2), tiling.AXIS, None) for k, v in local.items()},),
+        out_specs=P(), check_vma=False), local)
+    # phi' is sent as phase is, after the D3Q15 kernel: the phase ghosts count twice
+    phase = sum(tiling.sent_bytes({k: v}) for k, v in ghosts.items() if k.startswith("phase"))
+    assert gauge.value == tiling.sent_bytes(ghosts) + phase
+
+
 def test_pool_sweep_aggregates_worker_spans():
     """Every pipeline phase shows up in one trace, including the per-worker
     estimate batches, and worker events keep their own pid lane."""

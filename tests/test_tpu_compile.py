@@ -216,6 +216,93 @@ def test_lbm27_lowered_kernel_is_pinned(chip, block):
     assert _lowered_digest(lowered) == LBM27_DIGESTS[block]
 
 
+# the D3Q15 kernel's lowered module at 256^3, pinned as the D3Q27 kernel's is:
+# the ghost reads of a domain split over chips leave the one-chip kernel as it was
+LBM15_DIGESTS = {
+    (8, 32): "7edb62ca22bf3f650d7988f2650d716ae6db066ad7fcb6844517417e15ad3e53",
+    (8, 8): "a8b49f7758c71a9b93e67207b1021ed7c8a2ea24efd013b4e95cc5c2c909160e",
+    (16, 16): "3c40f37303274d1263abb8e0fadfe692b51dfaa30d23ee3b307393a905229f20",
+}
+
+
+@pytest.mark.parametrize("block", list(LBM15_DIGESTS), ids=lambda b: f"{b[0]}x{b[1]}")
+def test_lbm15_lowered_kernel_is_pinned(chip, block):
+    struct, machine = chip
+    nz, ny, nx = LBM27
+    lowered = jax.jit(lambda f, p, v: lbm_step_pallas(
+        f, p, v, block=block, vmem_limit_bytes=machine.vmem_usable)).lower(
+        struct((15, nz, ny, nx)), struct(LBM27), struct((3, nz, ny, nx)))
+    assert _lowered_digest(lowered) == LBM15_DIGESTS[block]
+
+
+LBM_CHAIN = (256, 1024, 256)  # lbm_twophase_y4.bulk: a 256^3 block on each of four chips
+
+
+@pytest.mark.parametrize("kernel", ["lbm_d3q15", "lbm_d3q27"])
+def test_lbm_chain_kernel_compiles_at_its_pick(chip, kernel):
+    """Each kernel of the two-phase step over a chain of four chips, built
+    with the ghost operands of a chip's block (one row of y each) at the
+    estimator's pick for that block, compiles for the chip."""
+    from jax.sharding import AbstractMesh
+
+    from repro.kernels import tiling
+    from repro.kernels.lbm_d3q15 import kernel as lbm15
+    from repro.kernels.lbm_d3q27 import kernel as lbm27
+
+    struct, machine = chip
+    mesh = AbstractMesh((4,), (tiling.AXIS,))
+    nz, ny, nx = tiling.local_shape(LBM_CHAIN, mesh)
+    select, module = (lbm_select, lbm15) if kernel == "lbm_d3q15" else (lbm27_select, lbm27)
+    pick, est = select(LBM_CHAIN, jnp.float32, machine=machine, mesh=mesh)
+    assert est.feasible and pick == (8, 32)
+    ghosts = {tiling.ghost_operand(op, side): struct((*((len(e),) if e else ()), nz, 1, nx))
+              for (op, side), e in tiling.ghost_rows(module.input_blocks(pick, nx, 32)).items()}
+    lead = 15 if kernel == "lbm_d3q15" else 27
+    run = lbm_step_pallas if kernel == "lbm_d3q15" else hydro_step_pallas
+    assert _compiles(
+        lambda a, p, v, gh: run(a, p, v, block=pick, vmem_limit_bytes=machine.vmem_usable,
+                                ghosts=gh),
+        struct((lead, nz, ny, nx)), struct((nz, ny, nx)), struct((3, nz, ny, nx)), ghosts)
+
+
+def test_twophase_chain_step_compiles_for_four_chips(topo, chip, monkeypatch):
+    """The two-phase step on 256x1024x256 split in y over the described
+    2x2's four chips compiles to the two kernels and eight collective
+    permutes of one-row planes (four before the D3Q15 kernel, f, phase and
+    g each way, and phi' each way after it), with no temporary the size of
+    a field: each chip holds its own 256^3 block in and out."""
+    from jax.sharding import AxisType, NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.kernels import entry
+    from repro.kernels import tiling
+    from repro.kernels.lbm_d3q27 import twophase_step
+
+    _, machine = chip
+    monkeypatch.setattr(entry, "device_machine", lambda: machine)
+    mesh = jax.make_mesh((4,), (tiling.AXIS,), axis_types=(AxisType.Auto,), devices=topo.devices)
+
+    def split(*lead):
+        return jax.ShapeDtypeStruct((*lead, *LBM_CHAIN), jnp.float32, sharding=NamedSharding(
+            mesh, P(*(None,) * (len(lead) + 1), tiling.AXIS, None)))
+
+    compiled = jax.jit(lambda f, g, p, v: twophase_step(f, g, p, v, mesh=mesh)).lower(
+        split(15), split(27), split(), split(3)).compile()
+    text = compiled.as_text()
+    starts = [line for line in text.splitlines() if re.search(r"= .*collective-permute-start\(", line)]
+    assert len(starts) == 8, starts
+    assert all(re.search(r"f32\[(\d+,)?256,1,256\]", line) for line in starts), starts
+    kernels = _kernel_instructions(jax.jit(lambda f, g, p, v: twophase_step(f, g, p, v, mesh=mesh)
+                                           ).lower(split(15), split(27), split(), split(3)))
+    assert sorted(k.split(" ")[0].split(".")[0] for k in kernels) == ["%lbm_d3q27", "%lbm_step"]
+    assert not re.search(r"all-gather|all-to-all", text)
+    memory = compiled.memory_analysis()
+    state = (15 + 27 + 1 + 3) * 4 * 256 ** 3  # one chip's block of f, g, phase and vel
+    assert memory.argument_size_in_bytes == state
+    assert 0 <= memory.output_size_in_bytes - state < 4096  # and the output tuple's table
+    assert memory.temp_size_in_bytes < 256 ** 3 * 4
+
+
 def _kernel_instructions(lowered) -> list[str]:
     """The compiled ``tpu_custom_call`` instructions of ``lowered``, each cut
     as the chip benchmark's trace reduction names a device op."""
